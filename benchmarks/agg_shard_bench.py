@@ -74,7 +74,7 @@ def _bench_cell(name: str, spec: dict, W: int, d: int, rounds: int) -> dict:
     jax.block_until_ready(jax.tree.leaves(server))
     ms = (time.perf_counter() - t0) / rounds * 1e3
 
-    row_dev = max(s.data.nbytes for s in st._rows.addressable_shards)
+    row_dev = max(st.row_bytes_by_device().values())
     srv_dev = max(s.data.nbytes for s in st._server_flat.addressable_shards)
     return {
         "model": name, "n_params": b.n_params, "W": W, "mesh": d,

@@ -15,6 +15,9 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 def main() -> None:
     from benchmarks import agg_bench, agg_shard_bench, fl_figures, \
         roofline, scale_bench, wire_bench
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     # CI smoke dispatch: run exactly one tiny sweep and exit (the full
     # table below is the local/nightly path).  One entry point per flag:
@@ -44,14 +47,13 @@ def main() -> None:
                          indent=2))
         return
 
-    # the full sweep tolerates any one bench dying (e.g. an optional dep
-    # missing from a minimal environment): the rest still report
+    # a benchmark that raises fails the whole run, naming itself
     for bench in (agg_bench.main, agg_shard_bench.main, wire_bench.main,
                   scale_bench.main):
         try:
             bench()
-        except Exception as e:                      # noqa: BLE001
-            print(f"[skipped] {bench.__module__}: {type(e).__name__}: {e}")
+        except Exception as e:
+            raise RuntimeError(f"benchmark {bench.__module__} failed") from e
         print()
 
     print("name,us_per_call,derived")
@@ -59,9 +61,8 @@ def main() -> None:
         t0 = time.time()
         try:
             derived = fn()
-        except Exception as e:                      # noqa: BLE001
-            print(f"{name},0,\"[skipped] {type(e).__name__}\"")
-            continue
+        except Exception as e:
+            raise RuntimeError(f"benchmark {name} failed") from e
         us = (time.time() - t0) * 1e6
         short = json.dumps(derived, default=lambda o: round(o, 3)
                            if isinstance(o, float) else o)

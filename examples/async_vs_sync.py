@@ -10,6 +10,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 from repro.core import (TABLE_4_1, make_setup, run_fl,
                         run_sequential_baseline, time_to_accuracy)
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def sparkline(history, t_max, width=60):
@@ -22,6 +23,7 @@ def sparkline(history, t_max, width=60):
 
 
 def main():
+    enable_compile_cache()
     setup = make_setup(TABLE_4_1["mnist_even"], seed=0, noise=0.2,
                        batch_size=64, het="extreme")
     alg2 = {"r": 10, "T0": 0.0, "A": 0.01}

@@ -16,9 +16,11 @@ from repro.core.selection import make_selector
 from repro.core.server import AggregationServer
 from repro.core.worker import FLWorker
 from repro.runtime import ElasticPool, FaultInjector
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     setup = make_setup(TABLE_4_1["mnist_even"], seed=0, noise=0.2,
                        batch_size=64, het="extreme")
     loop = EventLoop()

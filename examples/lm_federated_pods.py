@@ -26,9 +26,11 @@ from repro.configs import get_config
 from repro.core import federated
 from repro.data import synthetic_token_batches
 from repro.models import init_params
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=120)
     ap.add_argument("--pods", type=int, default=2)

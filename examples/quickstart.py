@@ -12,9 +12,11 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 from repro.core import TABLE_4_1, make_setup, run_fl, time_to_accuracy
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     setup = make_setup(TABLE_4_1["mnist_even"], seed=0, noise=0.2,
                        batch_size=64, het="extreme")
     print(f"10 workers, {setup.model_bytes/1e3:.0f} KB model, "

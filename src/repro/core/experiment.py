@@ -195,10 +195,10 @@ def run_fl(setup: FLSetup, *, mode: str = "sync", selector: str = "all",
     ``parallel.sharding.agg_mesh``): the packed server model, the (W, N)
     update-row buffer and every link's flat vectors split along the
     parameter axis, and the fused merge runs per shard — per-device live
-    bytes shrink ~linearly with mesh size.  ``server_mesh=1`` is
-    bit-identical to the default fused single-device path (``None``);
-    larger meshes match within the reduction-order LSB tolerance
-    documented in ROADMAP.md (CPU runs need
+    bytes shrink ~linearly with mesh size.  Any mesh size is
+    bit-identical to the default fused single-device path (``None``):
+    the merge is shard-local, and the model trees that workers train and
+    the server evaluates are replicated over the mesh (CPU runs need
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N``).
 
     ``topology`` turns on hierarchical multi-server federation

@@ -26,9 +26,10 @@ Sharded substrate: pass ``mesh=`` (a 1-D ``parallel.sharding.agg_mesh``)
 and the whole flat layer shards along the packed parameter axis N —
 ``ParamBundle`` pads N to ``BLOCK * n_shards`` divisibility and carries a
 ``NamedSharding`` (vectors ``P('agg')``, the (W, N) row buffer
-``P(None, 'agg')``), pack/unpack jits pin their outputs to it, and the
-fused merge dispatches per shard (``shard_map``-ed Pallas kernel on TPU,
-a GSPMD-partitioned XLA contraction elsewhere).  The packed layout keeps
+``P(None, 'agg')``), pack jits pin their outputs to it, unpack returns
+trees replicated over the mesh (what training and evaluation consume),
+and the fused merge dispatches per shard (``shard_map``-ed Pallas kernel
+on TPU, a GSPMD-partitioned XLA contraction elsewhere).  The packed layout keeps
 every worker's lane of a parameter on one device, so the W-reduce is
 shard-local, the merge needs no collective at all, and no host ever
 materialises the full (W, N) buffer — per-device live bytes shrink
@@ -123,14 +124,15 @@ class ParamBundle:
         self.shard_size = self.padded_size // self.n_shards
         if mesh is None:
             self.vec_sharding = self.row_sharding = None
-            vkw = rkw = {}
+            vkw = rkw = tkw = {}
         else:
             self.vec_sharding = psharding.agg_vec_sharding(mesh)
             self.row_sharding = psharding.agg_row_sharding(mesh)
             vkw = {"out_shardings": self.vec_sharding}
             rkw = {"out_shardings": self.row_sharding}
+            tkw = {"out_shardings": psharding.agg_tree_sharding(mesh)}
         self._pack = jax.jit(self._pack_impl, **vkw)
-        self._unpack = jax.jit(self._unpack_impl)
+        self._unpack = jax.jit(self._unpack_impl, **tkw)
         self._pack_many = jax.jit(self._pack_many_impl, **rkw)
         # stale rows beyond the live W are zeroed, not just weight-0-masked:
         # a non-finite value left by a past round would turn 0 * inf into
@@ -215,6 +217,9 @@ def bundle_for(template, mesh=None) -> ParamBundle:
 # --- fused merge ops -------------------------------------------------------
 # wvec = [server_scale, w_0 .. w_{Wcap-1}]; rows beyond the live W carry
 # weight 0, so capacity growth never changes the result — only the jit key.
+# Every contraction runs at full f32 precision, as the Pallas kernel does.
+HIGHEST = fedavg_agg.HIGHEST
+
 
 def _fused_mix(server_flat, rows, wvec, use_pallas: bool, interpret: bool):
     if use_pallas:
@@ -222,7 +227,7 @@ def _fused_mix(server_flat, rows, wvec, use_pallas: bool, interpret: bool):
                                           wvec[0], block_n=BLOCK,
                                           interpret=interpret)
     return wvec[0] * server_flat + jax.lax.dot_general(
-        wvec[1:], rows, (((0,), (0,)), ((), ())),
+        wvec[1:], rows, (((0,), (0,)), ((), ())), precision=HIGHEST,
         preferred_element_type=jnp.float32)
 
 
@@ -235,6 +240,7 @@ def _weighted_sum(rows, w, use_pallas: bool, interpret: bool):
         return fedavg_agg.fedavg_agg_flat(rows, w, block_n=BLOCK,
                                           interpret=interpret)
     return jax.lax.dot_general(w, rows, (((0,), (0,)), ((), ())),
+                               precision=HIGHEST,
                                preferred_element_type=jnp.float32)
 
 
@@ -261,7 +267,7 @@ def _sharded_mix_jit(mesh, use_pallas: bool, interpret: bool):
         rows = jax.lax.with_sharding_constraint(rows, rs)
         server_flat = jax.lax.with_sharding_constraint(server_flat, vs)
         return wvec[0] * server_flat + jax.lax.dot_general(
-            wvec[1:], rows, (((0,), (0,)), ((), ())),
+            wvec[1:], rows, (((0,), (0,)), ((), ())), precision=HIGHEST,
             preferred_element_type=jnp.float32)
 
     return jax.jit(mix, donate_argnums=(0,), out_shardings=vs)
@@ -279,6 +285,7 @@ def _sharded_wsum_jit(mesh, use_pallas: bool, interpret: bool):
                 interpret=interpret)
         rows = jax.lax.with_sharding_constraint(rows, rs)
         return jax.lax.dot_general(w, rows, (((0,), (0,)), ((), ())),
+                                   precision=HIGHEST,
                                    preferred_element_type=jnp.float32)
 
     return jax.jit(wsum, out_shardings=vs)
@@ -382,6 +389,13 @@ class FlatServerState:
     @property
     def capacity(self) -> int:
         return 0 if self._rows is None else int(self._rows.shape[0])
+
+    def row_bytes_by_device(self) -> Dict[str, int]:
+        """Bytes of the (W, N) row buffer held on each device."""
+        if self._rows is None:
+            return {}
+        return {str(s.device): int(s.data.nbytes)
+                for s in self._rows.addressable_shards}
 
     def _ensure_capacity(self, w: int):
         if self.capacity >= w:

@@ -32,15 +32,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
+# f32 contractions at full f32 precision: at the default, a TPU may run an
+# f32 matmul as a single bf16 pass, rounding every merged weight to bf16
+HIGHEST = jax.lax.Precision.HIGHEST
 
 def _agg_kernel(w_ref, x_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)        # (W, BN)
     w = w_ref[...].astype(jnp.float32)        # (1, W)
     o_ref[...] = jax.lax.dot_general(
-        w, x, (((1,), (0,)), ((), ())),
+        w, x, (((1,), (0,)), ((), ())), precision=HIGHEST,
         preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
@@ -50,7 +52,7 @@ def _mix_kernel(w_ref, x_ref, s_ref, o_ref):
     s = s_ref[...].astype(jnp.float32)        # (1, BN)
     w = w_ref[...].astype(jnp.float32)        # (1, W+1)
     acc = jax.lax.dot_general(
-        w[:, 1:], x, (((1,), (0,)), ((), ())),
+        w[:, 1:], x, (((1,), (0,)), ((), ())), precision=HIGHEST,
         preferred_element_type=jnp.float32)
     o_ref[...] = (w[:, 0:1] * s + acc).astype(o_ref.dtype)
 
@@ -259,10 +261,10 @@ def fedavg_mix_flat_sharded(stacked: jnp.ndarray, weights: jnp.ndarray,
             out = jax.lax.all_gather(out, axis, tiled=True)
         return out
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P(), P(None, axis), P(axis)),
-                     out_specs=P() if gather else P(axis),
-                     check_rep=False)(wvec, stacked, server)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(), P(None, axis), P(axis)),
+                         out_specs=P() if gather else P(axis),
+                         check_vma=False)(wvec, stacked, server)
 
 
 def fedavg_agg_flat_sharded(stacked: jnp.ndarray, weights: jnp.ndarray, *,
@@ -281,9 +283,9 @@ def fedavg_agg_flat_sharded(stacked: jnp.ndarray, weights: jnp.ndarray, *,
             out = jax.lax.all_gather(out, axis, tiled=True)
         return out
 
-    return shard_map(local, mesh=mesh, in_specs=(P(), P(None, axis)),
-                     out_specs=P() if gather else P(axis),
-                     check_rep=False)(weights, stacked)
+    return jax.shard_map(local, mesh=mesh, in_specs=(P(), P(None, axis)),
+                         out_specs=P() if gather else P(axis),
+                         check_vma=False)(weights, stacked)
 
 
 def server_opt_step_flat_sharded(prev, merged, m, v, scalars, *,
@@ -301,18 +303,19 @@ def server_opt_step_flat_sharded(prev, merged, m, v, scalars, *,
             return server_opt_step_flat(p, mg, mm, vv, sc, adam=True,
                                         block_n=block_n,
                                         interpret=interpret)
-        return shard_map(local, mesh=mesh,
-                         in_specs=(P(), P(axis), P(axis), P(axis), P(axis)),
-                         out_specs=(P(axis), P(axis), P(axis)),
-                         check_rep=False)(scalars, prev, merged, m, v)
+        return jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(P(), P(axis), P(axis), P(axis), P(axis)),
+            out_specs=(P(axis), P(axis), P(axis)),
+            check_vma=False)(scalars, prev, merged, m, v)
 
     def local_mom(sc, p, mg, mm):
         new, mo, _ = server_opt_step_flat(p, mg, mm, None, sc, adam=False,
                                           block_n=block_n,
                                           interpret=interpret)
         return new, mo
-    new, mo = shard_map(local_mom, mesh=mesh,
-                        in_specs=(P(), P(axis), P(axis), P(axis)),
-                        out_specs=(P(axis), P(axis)),
-                        check_rep=False)(scalars, prev, merged, m)
+    new, mo = jax.shard_map(local_mom, mesh=mesh,
+                            in_specs=(P(), P(axis), P(axis), P(axis)),
+                            out_specs=(P(axis), P(axis)),
+                            check_vma=False)(scalars, prev, merged, m)
     return new, mo, None

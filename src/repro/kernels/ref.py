@@ -6,6 +6,9 @@ import math
 import jax
 import jax.numpy as jnp
 
+# the merge oracles contract at full f32 precision, as the kernels do
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def reference_attention(q, k, v, *, causal=True, window=0, softcap=0.0):
     """O(S^2) attention. q:(B,S,H,D); k,v:(B,T,Kv,D)."""
@@ -33,7 +36,8 @@ def reference_attention(q, k, v, *, causal=True, window=0, softcap=0.0):
 def reference_fedavg(stacked, weights):
     """(W,N) x (W,) -> (N,)."""
     return jnp.einsum("wn,w->n", stacked.astype(jnp.float32),
-                      weights.astype(jnp.float32)).astype(stacked.dtype)
+                      weights.astype(jnp.float32),
+                      precision=HIGHEST).astype(stacked.dtype)
 
 
 def reference_fedavg_sharded(stacked, weights, server, server_scale,
@@ -51,7 +55,8 @@ def reference_fedavg_sharded(stacked, weights, server, server_scale,
         sl = slice(d * S, (d + 1) * S)
         outs.append(server_scale * server[sl].astype(jnp.float32)
                     + jnp.einsum("wn,w->n", stacked[:, sl].astype(jnp.float32),
-                                 weights.astype(jnp.float32)))
+                                 weights.astype(jnp.float32),
+                                 precision=HIGHEST))
     return jnp.concatenate(outs).astype(server.dtype)
 
 

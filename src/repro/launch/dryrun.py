@@ -131,17 +131,13 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool, fl: bool = False,
         rec["n_microbatch"] = (cfg.microbatches
                                if SHAPES[shape]["kind"] == "train" else None)
         # set_mesh (context-manager form) exposes the abstract mesh to
-        # trace-time sharding constraints (sequence parallelism etc.);
-        # jax <= 0.4.x spells it `with mesh:`
-        set_mesh = getattr(jax.sharding, "set_mesh", None)
-        with (set_mesh(mesh) if set_mesh is not None else mesh):
+        # trace-time sharding constraints (sequence parallelism etc.)
+        with jax.sharding.set_mesh(mesh):
             lowered_steps = lower_cell(arch, shape, mesh, fl=fl)
         for name, lowered in lowered_steps:
             t1 = time.time()
             compiled = lowered.compile()
             cost = compiled.cost_analysis()
-            if isinstance(cost, (list, tuple)):   # jax <= 0.4.x: per-device list
-                cost = cost[0] if cost else None
             mem = hlo_analysis.memory_summary(compiled)
             parsed = hlo_cost.analyze(compiled.as_text())
             terms = hlo_analysis.roofline_terms(parsed, cost)

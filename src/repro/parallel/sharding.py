@@ -73,6 +73,15 @@ def agg_row_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, agg_row_spec())
 
 
+def agg_tree_sharding(mesh: Mesh) -> NamedSharding:
+    """Unpacked model tree: every leaf replicated over the server mesh.
+    Its consumers (a worker's local training, evaluation) then run the
+    same unpartitioned program as on one device; leaves split along the
+    packed axis would partition their contractions across the mesh and
+    change the reduction order."""
+    return NamedSharding(mesh, P())
+
+
 def dp_axes(mesh) -> Tuple[str, ...]:
     return ("pod", "data") if "pod" in mesh.axis_names else ("data",)
 
@@ -111,26 +120,11 @@ def pod_axis_is_vmapped():
         _TLS.no_pod = prev
 
 
-def _abstract_mesh():
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        return get()
-    # jax <= 0.4.x: no public accessor — read the trace-time context stack,
-    # falling back to the `with mesh:` thread-resources environment
-    from jax._src import mesh as _mesh_lib
-    stack = _mesh_lib.get_abstract_mesh()
-    am = (stack[-1] if stack else None) if isinstance(stack, tuple) else stack
-    if am is None or getattr(am, "empty", True):
-        env = _mesh_lib.thread_resources.env.physical_mesh
-        am = None if env.empty else env
-    return am
-
-
 def current_mesh_axes():
     """Axis-name -> size of the mesh active at trace time ({} outside jit /
     without a mesh context). Hides the pod axis under fl vmap."""
-    am = _abstract_mesh()
-    if am is None or am.empty:
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty:
         return {}
     axes = dict(am.shape)
     if getattr(_TLS, "no_pod", False):

@@ -17,7 +17,9 @@ differ in the last mantissa bits (~1e-8 per round, compounding over
 rounds).  Sharding adds NOTHING on top: the packed (W, N) layout keeps
 the W-reduce shard-local, so the sharded merge is asserted BIT-identical
 to the fused single-device merge at every mesh size, while sharded-vs-
-tree comparisons use ``TOL_TREE``.
+tree comparisons use ``TOL_TREE``.  Whole ``run_fl`` histories are
+bit-identical across mesh sizes too: unpacked trees are replicated over
+the mesh, so worker training and evaluation never run partitioned.
 
 Device counts: the default tier sees one CPU device (conftest pops
 XLA_FLAGS), which activates only the d=1 cases in-process — plus ONE
@@ -217,11 +219,31 @@ def test_per_device_row_buffer_shrinks_linearly(d):
     st = flatbuf.FlatServerState(t, mesh=mesh)
     st.merge(t, [_ragged_tree(i) for i in range(4)], [1.0] * 4, alpha=0.5)
     total = 4 * st.bundle.padded_size * 4            # (W, N) f32 bytes
-    per_dev = {s.data.nbytes for s in st._rows.addressable_shards}
-    assert per_dev == {total // d}
+    per_dev = st.row_bytes_by_device()
+    assert len(per_dev) == d and set(per_dev.values()) == {total // d}
     # ... and the packed server mirror shards the same way
     srv = {s.data.nbytes for s in st._server_flat.addressable_shards}
     assert srv == {st.bundle.padded_size * 4 // d}
+
+
+def _check_unpack_trains_like_one_device(mesh):
+    """Unpacked trees are replicated over the server mesh, so a worker's
+    local training on them runs the one-device program bit for bit (a
+    leaf split along the packed axis would partition the contraction)."""
+    setup = make_setup(TABLE_4_1["mnist_even"], **SETUP_KW)
+    shard = next(s for s in setup.shards if len(s["x"]))
+    b = flatbuf.bundle_for(setup.weights0, mesh)
+    tree = b.unpack(b.pack(setup.weights0))
+    assert all(leaf.sharding.is_fully_replicated
+               for leaf in jax.tree.leaves(tree))
+    got = setup.train_fn(tree, shard["x"], shard["y"], 20)
+    expect = setup.train_fn(setup.weights0, shard["x"], shard["y"], 20)
+    assert _bit_equal(got, expect)
+
+
+@pytest.mark.parametrize("d", MESH_SIZES)
+def test_unpacked_tree_trains_like_one_device(d):
+    _check_unpack_trains_like_one_device(_mesh(d))
 
 
 # ---------------- end-to-end system parity ----------------
@@ -231,9 +253,9 @@ from conftest import hist_rec as _rec   # noqa: E402
 
 @pytest.mark.parametrize("d", MESH_SIZES)
 def test_run_fl_sharded_history_parity(d):
-    """Full event-driven runs: server_mesh=1 bit-identical to the fused
-    path; larger meshes match counts/bytes exactly (raw transport — byte
-    sizes are static) and accuracy within the LSB tolerance."""
+    """Full event-driven runs at any server_mesh are bit-identical to the
+    fused path: the merge is shard-local and unpacked trees are
+    replicated, so training and evaluation run the one-device program."""
     _mesh(d)
     h0 = run_fl(make_setup(TABLE_4_1["mnist_even"], **SETUP_KW),
                 mode="sync", selector="all", epochs_per_round=2,
@@ -241,16 +263,7 @@ def test_run_fl_sharded_history_parity(d):
     h1 = run_fl(make_setup(TABLE_4_1["mnist_even"], **SETUP_KW),
                 mode="sync", selector="all", epochs_per_round=2,
                 max_rounds=3, server_mesh=d)
-    if d == 1:
-        assert _rec(h1) == _rec(h0)
-        return
-    assert [(p.version, p.n_updates, p.selected, p.up_bytes, p.down_bytes)
-            for p in h1] == \
-           [(p.version, p.n_updates, p.selected, p.up_bytes, p.down_bytes)
-            for p in h0]
-    for a, b in zip(h0, h1):
-        assert abs(a.accuracy - b.accuracy) < TOL_ACC
-        assert abs(a.time - b.time) < 1e-9
+    assert _rec(h1) == _rec(h0)
 
 
 @pytest.mark.parametrize("d", [1, 4])
@@ -267,14 +280,7 @@ def test_run_fl_sharded_compressed_codec_parity(d):
     h0 = run_fl(make_setup(TABLE_4_1["mnist_even"], **SETUP_KW), **kw)
     h1 = run_fl(make_setup(TABLE_4_1["mnist_even"], **SETUP_KW),
                 server_mesh=d, **kw)
-    if d == 1:
-        assert _rec(h1) == _rec(h0)
-        return
-    assert [(p.version, p.n_updates, p.up_bytes, p.down_bytes) for p in h1] \
-        == [(p.version, p.n_updates, p.up_bytes, p.down_bytes) for p in h0]
-    for a, b in zip(h0, h1):
-        assert abs(a.accuracy - b.accuracy) < TOL_ACC
-        assert abs(a.time - b.time) < 1e-9
+    assert _rec(h1) == _rec(h0)
 
 
 @pytest.mark.parametrize("d", [1, 4])
@@ -289,13 +295,7 @@ def test_run_fl_sharded_empty_round_noop(d):
     h1 = run_fl(make_setup(TABLE_4_1["mnist_even"], **SETUP_KW),
                 server_mesh=d, **kw)
     assert any(p.n_updates == 0 for p in h0[1:]), "expected a no-op round"
-    if d == 1:
-        assert _rec(h1) == _rec(h0)
-    else:
-        assert [(p.n_updates, p.selected) for p in h1] == \
-               [(p.n_updates, p.selected) for p in h0]
-        for a, b in zip(h0, h1):
-            assert abs(a.accuracy - b.accuracy) < TOL_ACC
+    assert _rec(h1) == _rec(h0)
 
 
 def test_run_fl_sharded_vs_forced_tree_path(monkeypatch):
@@ -327,8 +327,10 @@ def test_multidevice_parity_subprocess():
     if jax.device_count() >= 4:
         pytest.skip("already multi-device in-process")
     # REPRO_HOST_DEVICES, not XLA_FLAGS: this module imports conftest,
-    # which owns XLA_FLAGS (pops it, then re-derives it from the env var)
-    env = dict(os.environ, REPRO_HOST_DEVICES="4",
+    # which owns XLA_FLAGS (pops it, then re-derives it from the env var).
+    # JAX_PLATFORMS=cpu: a CPU parity check, and the parent may hold the
+    # one accelerator a child would otherwise wait for
+    env = dict(os.environ, REPRO_HOST_DEVICES="4", JAX_PLATFORMS="cpu",
                PYTHONPATH=str(Path(__file__).resolve().parents[1] / "src"))
     env.pop("XLA_FLAGS", None)
     out = subprocess.run([sys.executable, __file__, "--parity"],
@@ -353,8 +355,10 @@ def _subprocess_parity_main():
             assert _bit_equal(a, b), f"d={d} alpha={alpha}"
             assert _max_err(a, agg.mix_into(
                 server, agg._weighted_mean(ups, ws), alpha)) < TOL_TREE
-        per_dev = {s.data.nbytes for s in st._rows.addressable_shards}
-        assert per_dev == {4 * st.bundle.padded_size * 4 // d}
+        per_dev = st.row_bytes_by_device()
+        assert len(per_dev) == d
+        assert set(per_dev.values()) == {4 * st.bundle.padded_size * 4 // d}
+        _check_unpack_trains_like_one_device(mesh)
         # kernel vs oracle on the real mesh
         W, N = 3, flatbuf.BLOCK * d
         rows = jax.random.normal(jax.random.PRNGKey(d), (W, N))

@@ -29,8 +29,6 @@ def test_hlo_cost_counts_scan_trip_counts():
     assert abs(res["flops"] - expect) / expect < 0.05
     # XLA's own analysis undercounts the while body — ours must not
     xla_cost = compiled.cost_analysis()
-    if isinstance(xla_cost, (list, tuple)):       # jax <= 0.4.x returns a list
-        xla_cost = xla_cost[0]
     assert res["flops"] > 5 * float(xla_cost["flops"])
 
 

@@ -228,6 +228,32 @@ def test_merge_rows_matches_merge():
     assert _max_err(out_t, out_v) == 0.0
 
 
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["xla", "pallas"])
+@pytest.mark.parametrize("alpha", [1.0, 0.5])
+def test_merge_rows_matches_float64_at_f32_tolerance(use_pallas, alpha):
+    """The fused merge keeps f32 accuracy: against float64 arithmetic on
+    the exact f32 coefficients it hands the contraction, every element
+    stays within an f32 rounding bound of the weighted sum (an operand
+    rounded to bf16 would miss it by a factor of ~2^16)."""
+    W = 16
+    server = _ragged_tree(60)
+    b = flatbuf.bundle_for(server)
+    vecs = [b.pack(_ragged_tree(70 + i)) for i in range(W)]
+    ws = [1.0 / (1 + i % 3) for i in range(W)]
+    st = flatbuf.FlatServerState(server, use_pallas=use_pallas)
+    got = np.asarray(b.pack(st.merge_rows(server, vecs, ws, alpha)),
+                     np.float64)
+    coef = np.zeros(W + 1, np.float32)
+    coef[0] = 1.0 - alpha
+    coef[1:] = alpha * flatbuf.normalized_weights(ws)
+    terms = np.stack([np.asarray(b.pack(server))]
+                     + [np.asarray(v) for v in vecs]).astype(np.float64)
+    c = coef.astype(np.float64)[:, None]
+    ref = (c * terms).sum(0)
+    bound = (W + 2) * np.finfo(np.float32).eps * (np.abs(c * terms).sum(0))
+    assert np.all(np.abs(got - ref) <= bound)
+
+
 def test_delta_vec_matches_apply_delta():
     cur, new, base = _ragged_tree(1), _ragged_tree(2), _ragged_tree(3)
     st = flatbuf.FlatServerState(cur)

@@ -304,7 +304,9 @@ def test_chaos_process_kill_then_resume_books_close(tmp_path):
     child_py = tmp_path / "child.py"
     child_py.write_text(_CHILD_SRC.format(
         src=src, chaos_kw=_CHAOS_KW, ckpt_dir=str(d), run_kw=_CHAOS_RUN_KW))
-    env = dict(os.environ)
+    # a CPU durability test: the parent may hold the one accelerator the
+    # child would otherwise wait for
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     proc = subprocess.Popen([sys.executable, str(child_py)], env=env,
                             stdout=subprocess.PIPE,

@@ -38,7 +38,6 @@ from repro.parallel import sharding as psh
 
 MESH_SIZES = [1, 2, 4]
 TOL_TREE = 5e-6        # merge reduction-order drift feeding the optimizer
-TOL_ACC = 1e-5
 
 SETUP_KW = dict(seed=0, noise=0.25, batch_size=32, het="strong")
 RUN_KW = dict(mode="sync", selector="all", epochs_per_round=3, max_rounds=4)
@@ -169,15 +168,9 @@ def test_run_fl_sharded_parity(setup, fused_histories, name, kw, d):
     _mesh(d)
     h = run_fl(setup, **RUN_KW, server_opt=name, server_opt_kw=kw,
                server_mesh=d)
-    h0 = fused_histories[name]
-    if d == 1:
-        # 1-device mesh: same reduction order -> bit-identical
-        assert hist_rec(h) == hist_rec(h0)
-    else:
-        assert len(h) == len(h0)
-        for a, b in zip(h, h0):
-            assert abs(a.accuracy - b.accuracy) < TOL_ACC
-            assert a.time == b.time and a.version == b.version
+    # shard-local merge and optimizer step, replicated unpacked trees:
+    # bit-identical at every mesh size
+    assert hist_rec(h) == hist_rec(fused_histories[name])
 
 
 # ---------------- degenerate settings == server_opt=None ----------------
