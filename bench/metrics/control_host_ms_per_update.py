@@ -1,0 +1,11 @@
+"""Federation loop: milliseconds per merged update of the self time of
+the program's ``fl.dispatch``, ``fl.select`` and ``fl.respond`` spans
+(round opening, cohort draw and selection, response bookkeeping), with
+the codec, merge, training and evaluation spans inside them left out."""
+from bench import spans
+
+SPANS = ("fl.dispatch", "fl.select", "fl.respond")
+
+
+def read(ctx):
+    return spans.span_ms(ctx, SPANS, "updates")

@@ -32,7 +32,7 @@ UNIQUE_VECS = 16         # distinct update vectors cycled across W rows
 MEM_CAP = int(float(os.environ.get("REPRO_BENCH_MEM", 1.6e9)))
 
 MODELS = {
-    # agg_bench's ~1.07M-param ragged MLP regime
+    # a ~1.07M-param ragged MLP
     "mlp_1m": {"w1": (784, 1024), "b1": (1024,), "w2": (1024, 256),
                "b2": (256,), "w3": (256, 10), "b3": (10,)},
     # ~16.8M params: the "big" tier

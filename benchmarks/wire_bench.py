@@ -3,9 +3,9 @@ path (one pass over the packed f32 vector, ``kernels/topk_quant``) vs the
 per-leaf pytree ``ErrorFeedbackCompressor`` reference (leaf-local top-k +
 quantise, forced via its REPRO_AGG_PATH=tree branch).
 
-Config mirrors agg_bench: a ~1.07M-param ragged-leaf model; each "encode"
-is one worker update being prepared for the uplink. Reports ms/encode and
-exact bytes/update for every codec in the registry.
+Config: a ~1.07M-param ragged-leaf model; each "encode" is one worker
+update being prepared for the uplink. Reports ms/encode and exact
+bytes/update for every codec in the registry.
 
 Emits ``benchmarks/results/BENCH_wire.json``. Run directly or via
 ``benchmarks/run.py``.
@@ -22,7 +22,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 RESULTS = Path(__file__).resolve().parent / "results"
 
 ROUNDS = 20        # timed encodes per path
-HIDDEN = 1024      # ~1.07M params total (matches agg_bench)
+HIDDEN = 1024      # ~1.07M params total
 FRAC = 0.1
 
 

@@ -18,6 +18,7 @@ from repro.data.synth import (federated_split, make_classification_dataset,
                               partition_split)
 from repro.models import cnn
 from repro.parallel import sharding as psharding
+from repro.runtime.trace import to_device
 
 from .estimator import TimeEstimator, WorkerProfile
 from .events import EventLoop
@@ -139,15 +140,13 @@ def make_setup(batches_per_worker: Sequence[int], *,
 
 
 def cnn_train_wrapper(params, x, y, epochs, lr=0.01):
-    import jax.numpy as jnp
-    return cnn.cnn_sgd_train(params, jnp.asarray(x), jnp.asarray(y),
+    return cnn.cnn_sgd_train(params, to_device(x), to_device(y),
                              lr=lr, epochs=int(epochs))
 
 
 def mlp_train_wrapper(params, x, y, epochs, lr=0.1):
-    import jax.numpy as jnp
     from repro.models import mlp as mlp_mod
-    return mlp_mod.mlp_sgd_train(params, jnp.asarray(x), jnp.asarray(y),
+    return mlp_mod.mlp_sgd_train(params, to_device(x), to_device(y),
                                  lr=lr, epochs=int(epochs))
 
 
@@ -157,9 +156,8 @@ def mlp_prox_train_wrapper(params, x, y, epochs, lr=0.1, mu=0.0):
     # when the transport compresses), so the proximal anchor is the
     # global the worker actually holds — composing with lossy downlinks
     # needs no transport-side plumbing at all
-    import jax.numpy as jnp
     from repro.models import mlp as mlp_mod
-    return mlp_mod.mlp_prox_train(params, jnp.asarray(x), jnp.asarray(y),
+    return mlp_mod.mlp_prox_train(params, to_device(x), to_device(y),
                                   lr=lr, epochs=int(epochs), mu=mu)
 
 

@@ -50,6 +50,7 @@ import numpy as np
 
 from repro.kernels import fedavg_agg, pallas_flags
 from repro.parallel import sharding as psharding
+from repro.runtime import trace
 
 BLOCK = 512          # kernel tile width; pack pads N up to a multiple
 
@@ -299,7 +300,7 @@ def fused_merge(server_flat, rows, wvec, use_pallas: Optional[bool] = None,
     ``mesh`` the buffers are N-sharded and the pass runs per shard.
     """
     use_pallas, interpret = pallas_flags(use_pallas, interpret)
-    wv = jnp.asarray(wvec, jnp.float32)
+    wv = trace.to_device(wvec, jnp.float32)
     if mesh is not None:
         return _sharded_mix_jit(mesh, use_pallas, interpret)(
             server_flat, rows, wv)
@@ -314,7 +315,7 @@ def fused_weighted_sum(rows, w, use_pallas: Optional[bool] = None,
     ``mix_into`` short-circuits there, and ``0 * server`` would turn a
     non-finite server model into NaN instead of replacing it)."""
     use_pallas, interpret = pallas_flags(use_pallas, interpret)
-    wv = jnp.asarray(w, jnp.float32)
+    wv = trace.to_device(w, jnp.float32)
     if mesh is not None:
         return _sharded_wsum_jit(mesh, use_pallas, interpret)(rows, wv)
     return _weighted_sum_jit(rows, wv,
@@ -509,7 +510,9 @@ class FlatServerState:
 
     def win_write(self, row: int, vec) -> None:
         """Land one already-packed update vector in its claimed row."""
-        self._rows = self._win_set(self._rows, vec, np.int32(row))
+        idx = np.int32(row)
+        self._rows = self._win_set(self._rows, vec, idx)
+        trace.count("h2d_bytes", idx.nbytes)
         self._dirty.discard(row)
 
     def win_release(self, row: int) -> None:
@@ -523,6 +526,7 @@ class FlatServerState:
             return
         idx = np.fromiter(self._dirty, np.int32, len(self._dirty))
         self._rows = self._win_zero(self._rows, idx)
+        trace.count("h2d_bytes", idx.nbytes)
         self._dirty.clear()
 
     def merge_window(self, server_tree, rows: Sequence[int],

@@ -19,6 +19,8 @@ from typing import Callable, Dict, List, Optional
 import jax
 import numpy as np
 
+from repro.runtime import trace
+
 from . import aggregation as agg
 from . import flatbuf
 from . import server_opt as server_opt_mod
@@ -169,7 +171,7 @@ class AggregationServer:
         self._timeout_rid = 0
         self._noop_ev = None
         self.history: List[HistoryPoint] = [
-            HistoryPoint(0.0, 0, float(eval_fn(weights)), 0, 0)]
+            HistoryPoint(0.0, 0, self._accuracy(), 0, 0)]
         self.done = False
 
     # --- relationship (thesis §3.3.1) ---
@@ -229,7 +231,10 @@ class AggregationServer:
         self._dispatch_round()
 
     def _accuracy(self) -> float:
-        return float(self.eval_fn(self.weights))
+        # the evaluation contract returns a host float: a blocking read
+        trace.count("host_syncs")
+        with trace.span("fl.eval", version=self.version):
+            return float(self.eval_fn(self.weights))
 
     def _finish(self):
         self.done = True
@@ -272,56 +277,59 @@ class AggregationServer:
         self.warehouse.put(weights, uid=self.pointer.uid)
 
     def _dispatch_round(self):
-        if self.done:
-            return
-        if self._hold:
-            # held by the topology layer: remember that a round wants to
-            # open; release() re-enters once the new global is installed
-            self._pending_dispatch = True
-            return
-        if self.version >= self.max_rounds:
-            self._finish()
-            return
-        pool = self.profiles()
-        if self.cohort is not None:
-            pool = self._sample_cohort(pool)
-        selected = self.selector.select(pool)
-        self._round_id += 1
-        if not selected:
-            # nothing admitted (e.g. Alg2 with T=0): burn a no-op round so
-            # the policy's on_round_end can open the time budget (eq 3.3)
-            acc = self.history[-1].accuracy
-            self.selector.on_round_end(acc)
-            self.history.append(HistoryPoint(self.loop.now, self.version, acc,
-                                             0, 0, self.total_up_bytes,
-                                             self.total_down_bytes,
-                                             self.transport.total_retransmits))
-            self.transport.note_round(self.history[-1])
-            self.version += 1
-            self._noop_ev = self.loop.schedule(1e-3, self._noop_dispatch)
-            return
-        self._outstanding = set(selected)
-        self._round_open = True
-        base_version = self.version
-        rid = self._round_id
-        down_b = {wid: self._send_train(wid, base_version)
-                  for wid in selected}
-        if self.mode == "sync":
-            # straggler timeout: aggregate with whatever arrived; the round
-            # trip costs the *actual* encoded dispatch down (first-contact
-            # dispatches ship the full raw model even under a compressed
-            # downlink codec) plus the codec'd response up
-            up_b = self.transport.expected_up_bytes()
-            t_max = max(self.est.t_one(self.workers[w].profile) *
-                        self.epochs_per_round +
-                        self.est.t_transmit(self.workers[w].profile,
-                                            down_b[w]) +
-                        self.est.t_transmit(self.workers[w].profile, up_b)
-                        for w in selected)
-            self._timeout_rid = rid
-            self._timeout_ev = self.loop.schedule(
-                self.straggler_timeout_factor * max(t_max, 1e-3),
-                self._round_timeout, rid)
+        with trace.span("fl.dispatch", version=self.version):
+            if self.done:
+                return
+            if self._hold:
+                # held by the topology layer: remember that a round wants to
+                # open; release() re-enters once the new global is installed
+                self._pending_dispatch = True
+                return
+            if self.version >= self.max_rounds:
+                self._finish()
+                return
+            pool = self.profiles()
+            with trace.span("fl.select", pool=len(pool)):
+                if self.cohort is not None:
+                    pool = self._sample_cohort(pool)
+                selected = self.selector.select(pool)
+            self._round_id += 1
+            if not selected:
+                # nothing admitted (e.g. Alg2 with T=0): burn a no-op round
+                # so the policy's on_round_end can open the time budget
+                # (eq 3.3)
+                acc = self.history[-1].accuracy
+                self.selector.on_round_end(acc)
+                self.history.append(HistoryPoint(
+                    self.loop.now, self.version, acc, 0, 0,
+                    self.total_up_bytes, self.total_down_bytes,
+                    self.transport.total_retransmits))
+                self.transport.note_round(self.history[-1])
+                self.version += 1
+                self._noop_ev = self.loop.schedule(1e-3, self._noop_dispatch)
+                return
+            self._outstanding = set(selected)
+            self._round_open = True
+            base_version = self.version
+            rid = self._round_id
+            down_b = {wid: self._send_train(wid, base_version)
+                      for wid in selected}
+            if self.mode == "sync":
+                # straggler timeout: aggregate with whatever arrived; the round
+                # trip costs the *actual* encoded dispatch down (first-contact
+                # dispatches ship the full raw model even under a compressed
+                # downlink codec) plus the codec'd response up
+                up_b = self.transport.expected_up_bytes()
+                t_max = max(self.est.t_one(self.workers[w].profile) *
+                            self.epochs_per_round +
+                            self.est.t_transmit(self.workers[w].profile,
+                                                down_b[w]) +
+                            self.est.t_transmit(self.workers[w].profile, up_b)
+                            for w in selected)
+                self._timeout_rid = rid
+                self._timeout_ev = self.loop.schedule(
+                    self.straggler_timeout_factor * max(t_max, 1e-3),
+                    self._round_timeout, rid)
 
     def _sample_cohort(self, pool):
         """Seeded per-round cohort draw: sample ``cohort`` of the ALIVE
@@ -368,108 +376,114 @@ class AggregationServer:
 
     # --- response handling (thesis §3.3.3 steps 8-9) ---
     def _on_response(self, res: TrainResult):
-        w = self.workers.get(res.worker_id)
-        if w is None:
-            return
-        # redeem FIRST (and unconditionally): redemption deletes the stored
-        # payload, so stale/late responses can't leak a model-sized buffer
-        # plus a live ticket in the worker's warehouse forever
-        payload = w.warehouse.redeem_ticket(res.weights_ticket)
-        self._inflight_w.discard(res.worker_id)
-        if self.done:
-            return
-        self.total_up_bytes += res.up_bytes   # the bytes crossed the wire
-        self.est.observe_training(res.worker_id,
-                                  res.t_train / max(res.epochs, 1))
-        self.est.observe_transmit(res.worker_id, res.t_up, res.up_bytes)
-        staleness = self.version - res.base_version
-        if self.population is not None:
-            self.population.note_response(res.worker_id, res.base_version,
-                                          staleness)
-        if self.mode == "sync" and staleness > 0:
-            # thesis: sync ignores results that straddle an aggregation —
-            # but the encoded mass must go back into the link's EF residual
-            # or it is silently lost from the error-feedback contract
-            self.transport.link(res.worker_id).restore_uplink(payload)
-            return
-        link = self.transport.link(res.worker_id)
-        if self._use_vec:
-            # fast path: decode straight to a packed flat vector (for
-            # compressed codecs: base + dequantised delta in one fused
-            # pass); it lands in the (W, N) row buffer at merge time
-            weights = link.decode_up_vec(payload)
-        else:
-            weights = link.decode_up_tree(payload)
-        if self.async_delta and self.mode == "async":
-            base = self._dispatch_base.get(res.worker_id, self.weights)
-            if self._use_vec:
-                # delta-accumulate in flat-vector space: cur + (new - base);
-                # delta codecs already hold the packed base on the link
-                base_vec = (link.tx_base if self.transport.tracks_tx_base
-                            else self._flat.bundle.pack(base))
-                weights = self._flat.delta_vec(self.weights, weights,
-                                               base_vec)
-            elif self._flat is not None:
-                # delta-accumulate on packed buffers: cur + (new - base)
-                # in one fused pass instead of a per-leaf tree-map
-                weights = self._flat.apply_delta(self.weights, weights, base)
-            else:
-                weights = jax.tree.map(
-                    lambda cur, new, b: cur + (new - b), self.weights, weights,
-                    base)
-        if self._window:
-            # streaming cohort-windowed merge: the decoded vector lands in
-            # a claimed window row NOW, and from here on this update is
-            # identified by its row INDEX — `_cache`/`_latest` carry the
-            # int through the existing rebuild logic untouched, and the
-            # merge contracts the window with weights scattered by row.
-            # A re-responding worker (async latest-table) overwrites its
-            # own stable row.
-            row = self._row_of.get(res.worker_id)
-            if row is None:
-                row = self._flat.win_claim()
-                self._row_of[res.worker_id] = row
-            self._flat.win_write(row, weights)
-            weights = row
-        self._outstanding.discard(res.worker_id)
-        if self.mode == "async":
-            if self.async_latest_table:
-                # eq 2.2/2.4: the async aggregate averages *each worker's
-                # latest response* (whatever server version it was based
-                # on), staleness-weighted at merge time.
-                self._latest[res.worker_id] = (weights, res.base_version,
-                                               max(res.n_batches, 1))
-                self._cache = [
-                    agg.WorkerUpdate(weights=wt,
-                                     staleness=self.version - bv,
-                                     n_data=nd)
-                    for (wt, bv, nd) in self._latest.values()]
+        with trace.span("fl.respond", worker=res.worker_id,
+                        version=res.base_version):
+            w = self.workers.get(res.worker_id)
+            if w is None:
+                return
+            # redeem FIRST (and unconditionally): redemption deletes the stored
+            # payload, so stale/late responses can't leak a model-sized buffer
+            # plus a live ticket in the worker's warehouse forever
+            payload = w.warehouse.redeem_ticket(res.weights_ticket)
+            self._inflight_w.discard(res.worker_id)
+            if self.done:
+                return
+            self.total_up_bytes += res.up_bytes   # the bytes crossed the wire
+            self.est.observe_training(res.worker_id,
+                                      res.t_train / max(res.epochs, 1))
+            self.est.observe_transmit(res.worker_id, res.t_up, res.up_bytes)
+            staleness = self.version - res.base_version
+            if self.population is not None:
+                self.population.note_response(res.worker_id, res.base_version,
+                                              staleness)
+            if self.mode == "sync" and staleness > 0:
+                # thesis: sync ignores results that straddle an aggregation —
+                # but the encoded mass must go back into the link's EF residual
+                # or it is silently lost from the error-feedback contract
+                self.transport.link(res.worker_id).restore_uplink(payload)
+                return
+            link = self.transport.link(res.worker_id)
+            with trace.span("fl.decode_up", worker=res.worker_id):
+                if self._use_vec:
+                    # fast path: decode straight to a packed flat vector
+                    # (for compressed codecs: base + dequantised delta in
+                    # one fused pass); it lands in the (W, N) row buffer at
+                    # merge time
+                    weights = link.decode_up_vec(payload)
+                else:
+                    weights = link.decode_up_tree(payload)
+            if self.async_delta and self.mode == "async":
+                base = self._dispatch_base.get(res.worker_id, self.weights)
+                if self._use_vec:
+                    # delta-accumulate in flat-vector space: cur + (new -
+                    # base); delta codecs already hold the packed base on
+                    # the link
+                    base_vec = (link.tx_base if self.transport.tracks_tx_base
+                                else self._flat.bundle.pack(base))
+                    weights = self._flat.delta_vec(self.weights, weights,
+                                                   base_vec)
+                elif self._flat is not None:
+                    # delta-accumulate on packed buffers: cur + (new - base)
+                    # in one fused pass instead of a per-leaf tree-map
+                    weights = self._flat.apply_delta(self.weights, weights,
+                                                     base)
+                else:
+                    weights = jax.tree.map(
+                        lambda cur, new, b: cur + (new - b), self.weights,
+                        weights, base)
+            if self._window:
+                # streaming cohort-windowed merge: the decoded vector lands in
+                # a claimed window row NOW, and from here on this update is
+                # identified by its row INDEX — `_cache`/`_latest` carry the
+                # int through the existing rebuild logic untouched, and the
+                # merge contracts the window with weights scattered by row.
+                # A re-responding worker (async latest-table) overwrites its
+                # own stable row.
+                row = self._row_of.get(res.worker_id)
+                if row is None:
+                    row = self._flat.win_claim()
+                    self._row_of[res.worker_id] = row
+                self._flat.win_write(row, weights)
+                weights = row
+            self._outstanding.discard(res.worker_id)
+            if self.mode == "async":
+                if self.async_latest_table:
+                    # eq 2.2/2.4: the async aggregate averages *each worker's
+                    # latest response* (whatever server version it was based
+                    # on), staleness-weighted at merge time.
+                    self._latest[res.worker_id] = (weights, res.base_version,
+                                                   max(res.n_batches, 1))
+                    self._cache = [
+                        agg.WorkerUpdate(weights=wt,
+                                         staleness=self.version - bv,
+                                         n_data=nd)
+                        for (wt, bv, nd) in self._latest.values()]
+                else:
+                    self._cache.append(agg.WorkerUpdate(
+                        weights=weights, staleness=staleness,
+                        n_data=max(res.n_batches, 1)))
+                if len(self._cache) >= self.async_min_updates:
+                    self._aggregate()
+                else:
+                    self._cache = []
+                    if self._window and not self.async_latest_table:
+                        # discarded below-min updates: recycle their rows
+                        for row in self._row_of.values():
+                            self._flat.win_release(row)
+                        self._row_of.clear()
+                if not self.done:
+                    if self._hold:
+                        self._held.append(res.worker_id)
+                    else:
+                        self._send_train(res.worker_id, self.version)
             else:
                 self._cache.append(agg.WorkerUpdate(
                     weights=weights, staleness=staleness,
                     n_data=max(res.n_batches, 1)))
-            if len(self._cache) >= self.async_min_updates:
-                self._aggregate()
-            else:
-                self._cache = []
-                if self._window and not self.async_latest_table:
-                    # discarded below-min updates: recycle their rows
-                    for row in self._row_of.values():
-                        self._flat.win_release(row)
-                    self._row_of.clear()
-            if not self.done:
-                if self._hold:
-                    self._held.append(res.worker_id)
-                else:
-                    self._send_train(res.worker_id, self.version)
-        else:
-            self._cache.append(agg.WorkerUpdate(weights=weights,
-                                                staleness=staleness,
-                                                n_data=max(res.n_batches, 1)))
-            if not self._outstanding:
-                self._aggregate()
-                if not self.done:
-                    self._dispatch_round()
+                if not self._outstanding:
+                    self._aggregate()
+                    if not self.done:
+                        self._dispatch_round()
 
     def _noop_dispatch(self):
         """The deferred re-dispatch of an empty-selection round (tracked so
@@ -516,50 +530,54 @@ class AggregationServer:
         if not self._cache:
             return
         self._round_open = False
-        # async merges are damped (FedAsync-style server mixing): a single
-        # worker's response nudges the global model instead of replacing it,
-        # scaled down further for stale responses (eq 2.4 family).
-        if self.mode == "async" and not self.async_latest_table:
-            stale = max(u.staleness for u in self._cache)
-            alpha = self.async_alpha * (1.0 + stale) ** (-self.async_stale_pow)
-        else:
-            alpha = 1.0
-        ws = agg.update_weights(self.aggregator, self._cache)
-        if self._window and ws is not None:
-            # cohort window: cache entries carry claimed row indices; the
-            # merge contracts the O(cohort x N) window with each weight
-            # scattered to its row (same fused kernel as merge_rows)
-            self.weights = self._flat.merge_window(
-                self.weights, [u.weights for u in self._cache], ws, alpha)
-            if not (self.mode == "async" and self.async_latest_table):
-                # sync / single-arrival async: merged rows are dead —
-                # recycle them (latest-table workers keep stable rows,
-                # matching the legacy table's keep-latest semantics)
-                for row in self._row_of.values():
-                    self._flat.win_release(row)
-                self._row_of.clear()
-        elif self._use_vec and ws is not None:
-            # fast path: responses were decoded straight to packed flat
-            # vectors; land them in the (W, N) row buffer and fuse the
-            # staleness-weighted sum + alpha-mix in one pass
-            self.weights = self._flat.merge_rows(
-                self.weights, [u.weights for u in self._cache], ws, alpha)
-        elif self._flat is not None and ws is not None:
-            # cache holds pytrees (non-flat transport): pack-and-merge
-            self.weights = self._flat.merge(
-                self.weights, [u.weights for u in self._cache], ws, alpha)
-        else:
-            merged = agg.AGGREGATORS[self.aggregator](self._cache)
-            mixed = agg.mix_into(self.weights, merged, alpha)
-            if self.server_opt is not None:
-                # tree fallback (REPRO_AGG_PATH=tree / non-packable
-                # weights): the per-leaf reference optimizer path — the
-                # flat substrate applies the fused pass in _finish instead
-                mixed = self.server_opt.step_tree(self.weights, mixed)
-            self.weights = mixed
-        # the pointer names the *model*: overwrite in place, uid stays stable
-        # (workers' ACLs hold this pointer — thesis §3.3.1 step 7)
-        self.warehouse.put(self.weights, uid=self.pointer.uid)
+        with trace.span("fl.merge", version=self.version + 1,
+                        rows=len(self._cache)):
+            # async merges are damped (FedAsync-style server mixing): a
+            # single worker's response nudges the global model instead of
+            # replacing it, scaled down further for stale responses (eq 2.4
+            # family).
+            if self.mode == "async" and not self.async_latest_table:
+                stale = max(u.staleness for u in self._cache)
+                alpha = (self.async_alpha
+                         * (1.0 + stale) ** (-self.async_stale_pow))
+            else:
+                alpha = 1.0
+            ws = agg.update_weights(self.aggregator, self._cache)
+            if self._window and ws is not None:
+                # cohort window: cache entries carry claimed row indices; the
+                # merge contracts the O(cohort x N) window with each weight
+                # scattered to its row (same fused kernel as merge_rows)
+                self.weights = self._flat.merge_window(
+                    self.weights, [u.weights for u in self._cache], ws, alpha)
+                if not (self.mode == "async" and self.async_latest_table):
+                    # sync / single-arrival async: merged rows are dead —
+                    # recycle them (latest-table workers keep stable rows,
+                    # matching the legacy table's keep-latest semantics)
+                    for row in self._row_of.values():
+                        self._flat.win_release(row)
+                    self._row_of.clear()
+            elif self._use_vec and ws is not None:
+                # fast path: responses were decoded straight to packed flat
+                # vectors; land them in the (W, N) row buffer and fuse the
+                # staleness-weighted sum + alpha-mix in one pass
+                self.weights = self._flat.merge_rows(
+                    self.weights, [u.weights for u in self._cache], ws, alpha)
+            elif self._flat is not None and ws is not None:
+                # cache holds pytrees (non-flat transport): pack-and-merge
+                self.weights = self._flat.merge(
+                    self.weights, [u.weights for u in self._cache], ws, alpha)
+            else:
+                merged = agg.AGGREGATORS[self.aggregator](self._cache)
+                mixed = agg.mix_into(self.weights, merged, alpha)
+                if self.server_opt is not None:
+                    # tree fallback (REPRO_AGG_PATH=tree / non-packable
+                    # weights): the per-leaf reference optimizer path — the
+                    # flat substrate applies the fused pass in _finish instead
+                    mixed = self.server_opt.step_tree(self.weights, mixed)
+                self.weights = mixed
+            # the pointer names the *model*: overwrite in place, uid stays
+            # stable (workers' ACLs hold this pointer — thesis §3.3.1 step 7)
+            self.warehouse.put(self.weights, uid=self.pointer.uid)
         n_upd = len(self._cache)
         self._cache = []
         if self.max_resident_links is not None:

@@ -55,6 +55,7 @@ import numpy as np
 
 from repro.kernels import fedavg_agg, pallas_flags
 from repro.parallel import sharding as psharding
+from repro.runtime import trace
 
 
 def _jit_step(mesh, use_pallas: bool, interpret: bool, adam: bool):
@@ -149,7 +150,7 @@ class ServerOpt:
             self._v = jnp.zeros_like(prev)
         new, self._m, v = _step_fn(flat.mesh, flat.use_pallas, self.adam)(
             prev, merged, self._m, self._v,
-            jnp.asarray(self._scalars(), jnp.float32))
+            trace.to_device(self._scalars(), jnp.float32))
         if self.adam:
             self._v = v
         return new

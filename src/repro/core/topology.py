@@ -75,6 +75,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 import jax
 
 from repro.parallel import sharding as psharding
+from repro.runtime import trace
 
 from . import aggregation as agg
 from . import flatbuf
@@ -253,7 +254,13 @@ class Topology:
         # passthrough: finalize() replaces the root history with the
         # leaf's verbatim, so seeding it with an eval would be dead work
         self.history: List[HistoryPoint] = [] if config.passthrough else [
-            HistoryPoint(0.0, 0, float(eval_fn(weights)), 0, 0)]
+            HistoryPoint(0.0, 0, self._accuracy(), 0, 0)]
+
+    def _accuracy(self) -> float:
+        # as AggregationServer._accuracy: one blocking read per evaluation
+        trace.count("host_syncs")
+        with trace.span("fl.eval", version=self.version):
+            return float(self.eval_fn(self.weights))
 
     # --- wiring ---
     def _bind_tuner(self, tr) -> None:
@@ -463,7 +470,7 @@ class Topology:
                 mixed = self.server_opt.step_tree(self.weights, mixed)
             self.weights = mixed
         self.version += 1
-        acc = float(self.eval_fn(self.weights))
+        acc = self._accuracy()
         alive = sum(1 for lf in self.leaves.values() if not lf.dead)
         self.history.append(HistoryPoint(self.loop.now, self.version, acc,
                                          len(ups), alive,

@@ -193,6 +193,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import topk_quant
+from repro.runtime import trace
 
 from . import flatbuf
 
@@ -332,7 +333,9 @@ def ef_topk_encode(x: jnp.ndarray, *, n_params: int, frac: float,
     the new error-feedback memory, and ``wire_bytes`` the exact cost per
     the codec table."""
     thresh = topk_threshold(x, topk_k(n_params, frac), n_params)
-    kept = int(_kept_count(x, thresh))
+    with trace.span("fl.sync", site="kept_count"):
+        kept = int(_kept_count(x, thresh))
+    trace.count("host_syncs")
     if quantize:
         scale = _int8_scale(x)
         q, resid = topk_quant.topk_quant_encode(x, thresh, scale,
@@ -759,49 +762,50 @@ class Link:
         return self.t.spec_down.delta
 
     def encode_down(self, weights_tree) -> Payload:
-        t = self.t
-        sd, frac = t.resolve_down(self)
-        if not sd.delta:
-            if t.tracks_tx_base:
-                # remember the packed base so the uplink delta decodes
-                self.tx_base = t._pack_down(weights_tree)
-            payload = Payload("raw", t.raw_bytes, weights_tree)
-            if t.auto_down:
-                # an auto-resolved raw dispatch (warmup, backbone, or an
-                # unmeasured link) still rides the ack machinery: the
-                # fetch-complete ack establishes the base later delta
-                # dispatches encode against (touches no residual, so it
-                # joins no revert chain)
+        with trace.span("fl.encode_down", worker=self.worker_id):
+            t = self.t
+            sd, frac = t.resolve_down(self)
+            if not sd.delta:
+                if t.tracks_tx_base:
+                    # remember the packed base so the uplink delta decodes
+                    self.tx_base = t._pack_down(weights_tree)
+                payload = Payload("raw", t.raw_bytes, weights_tree)
+                if t.auto_down:
+                    # an auto-resolved raw dispatch (warmup, backbone, or an
+                    # unmeasured link) still rides the ack machinery: the
+                    # fetch-complete ack establishes the base later delta
+                    # dispatches encode against (touches no residual, so it
+                    # joins no revert chain)
+                    self._pending_down = (payload, None, None)
+                return payload
+            vec = t._pack_down(weights_tree)
+            if self.acked_base is None:
+                # first dispatch: the worker holds no base yet -> raw fallback
+                # (touches no residual, so it joins no revert chain)
+                self.tx_base = vec
+                payload = Payload("raw", t.raw_bytes, weights_tree)
                 self._pending_down = (payload, None, None)
+                return payload
+            # the delta vs the worker's ACTUAL (acked) state is already the
+            # error-feedback-corrected quantity: it re-carries every bit of
+            # mass past dispatches dropped, so nothing is added on top — an
+            # explicit residual term here would count that deficit twice per
+            # dispatch and diverge.  For EF codecs _codec_encode still emits
+            # the residual OUTPUT (x - recon = the worker's post-fetch
+            # deficit), the genuine per-link downlink EF memory.
+            base = self.acked_base
+            delta = vec - base
+            entry = self._ack.push()             # joins the revert chain
+            payload, new_res = self._codec_encode(delta, None, sd, frac)
+            self._ack.down_residual = entry[1] = new_res
+            # the worker-visible model after this fetch (== what decode_down
+            # produces, same fused op on the same inputs): the uplink base
+            self.tx_base = self._codec_apply(payload.data, sd, base)
+            # the pending entry pins the encode-time base: a multi-server peer
+            # may advance the shared ack before this fetch completes, and the
+            # delta only decodes against the base it was cut from
+            self._pending_down = (payload, entry, base)
             return payload
-        vec = t._pack_down(weights_tree)
-        if self.acked_base is None:
-            # first dispatch: the worker holds no base yet -> raw fallback
-            # (touches no residual, so it joins no revert chain)
-            self.tx_base = vec
-            payload = Payload("raw", t.raw_bytes, weights_tree)
-            self._pending_down = (payload, None, None)
-            return payload
-        # the delta vs the worker's ACTUAL (acked) state is already the
-        # error-feedback-corrected quantity: it re-carries every bit of
-        # mass past dispatches dropped, so nothing is added on top — an
-        # explicit residual term here would count that deficit twice per
-        # dispatch and diverge.  For EF codecs _codec_encode still emits
-        # the residual OUTPUT (x - recon = the worker's post-fetch
-        # deficit), the genuine per-link downlink EF memory.
-        base = self.acked_base
-        delta = vec - base
-        entry = self._ack.push()             # joins the revert chain
-        payload, new_res = self._codec_encode(delta, None, sd, frac)
-        self._ack.down_residual = entry[1] = new_res
-        # the worker-visible model after this fetch (== what decode_down
-        # produces, same fused op on the same inputs): the uplink base
-        self.tx_base = self._codec_apply(payload.data, sd, base)
-        # the pending entry pins the encode-time base: a multi-server peer
-        # may advance the shared ack before this fetch completes, and the
-        # delta only decodes against the base it was cut from
-        self._pending_down = (payload, entry, base)
-        return payload
 
     def decode_down_vec(self, payload: Payload) -> jnp.ndarray:
         """Payload -> packed flat f32 vector of the dispatched model,
@@ -891,29 +895,30 @@ class Link:
         return self.t.expected_up_bytes()
 
     def encode_up(self, new_tree) -> Payload:
-        spec, frac = self.t.resolve_up(self)
-        if not spec.delta:                       # raw: ship the tree as-is
-            payload = Payload(spec.name, self.t.raw_bytes, new_tree)
+        with trace.span("fl.encode_up", worker=self.worker_id):
+            spec, frac = self.t.resolve_up(self)
+            if not spec.delta:                   # raw: ship the tree as-is
+                payload = Payload(spec.name, self.t.raw_bytes, new_tree)
+                if self.t.auto_up:
+                    # raw ships absolute weights and cannot carry EF mass:
+                    # park the residual for the next compressed dispatch
+                    # (nothing consumed, so nothing to snapshot)
+                    self._up_restore = None
+                return payload
+            vec = self.t.bundle.pack(new_tree)
+            prev_res = self.residual
+            payload, self.residual = self._codec_encode(
+                vec - self.tx_base, prev_res, spec, frac)
             if self.t.auto_up:
-                # raw ships absolute weights and cannot carry EF mass:
-                # park the residual for the next compressed dispatch
-                # (nothing consumed, so nothing to snapshot)
-                self._up_restore = None
+                if not spec.ef and prev_res is not None:
+                    # auto codec seam: the carried residual was folded into
+                    # this exact/quantised delta, so the memory ends here —
+                    # snapshot it so a cancelled dispatch restores the mass
+                    self._up_restore = (payload, prev_res)
+                    self.residual = None
+                else:
+                    self._up_restore = None
             return payload
-        vec = self.t.bundle.pack(new_tree)
-        prev_res = self.residual
-        payload, self.residual = self._codec_encode(
-            vec - self.tx_base, prev_res, spec, frac)
-        if self.t.auto_up:
-            if not spec.ef and prev_res is not None:
-                # auto codec seam: the carried residual was folded into
-                # this exact/quantised delta, so the memory ends here —
-                # snapshot it so a cancelled dispatch restores the mass
-                self._up_restore = (payload, prev_res)
-                self.residual = None
-            else:
-                self._up_restore = None
-        return payload
 
     def decode_up_vec(self, payload: Payload) -> jnp.ndarray:
         """Payload -> packed flat f32 vector of the worker's new absolute

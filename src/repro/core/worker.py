@@ -21,6 +21,8 @@ from typing import Callable, Dict, List, Optional
 
 import jax
 
+from repro.runtime import trace
+
 from .estimator import WorkerProfile
 from .events import EventLoop
 from .transport import Link, Payload, resume_transmit, transmit
@@ -160,7 +162,8 @@ class FLWorker:
                                          on_done),
                 direction="down")
             return
-        weights = link.decode_down(down)
+        with trace.span("fl.decode_down", worker=self.worker_id):
+            weights = link.decode_down(down)
         self._after_fetch(server_pointer, weights, base_version, epochs,
                           link, on_done, t_fetch)
 
@@ -184,18 +187,21 @@ class FLWorker:
         # mid-round, the server knows which base it holds.  Stateless
         # downlinks staged here only for the lossy channel skip the ack
         # bookkeeping entirely
-        if link.needs_down_ack:
-            weights = link.complete_fetch(down)
-        else:
-            weights = link.decode_down(down)
+        with trace.span("fl.decode_down", worker=self.worker_id):
+            if link.needs_down_ack:
+                weights = link.complete_fetch(down)
+            else:
+                weights = link.decode_down(down)
         self._after_fetch(server_pointer, weights, base_version, epochs,
                           link, on_done, 0.0)
 
-    def _train(self, weights, epochs: int):
-        if len(self.data["x"]):
-            return self.train_fn(weights, self.data["x"],
-                                 self.data["y"], epochs)
-        return weights              # no local data: echo (setup-3 zeros)
+    def _train(self, weights, epochs: int, base_version: int):
+        with trace.span("fl.train", worker=self.worker_id,
+                        version=base_version):
+            if len(self.data["x"]):
+                return self.train_fn(weights, self.data["x"],
+                                     self.data["y"], epochs)
+            return weights          # no local data: echo (setup-3 zeros)
 
     def _after_fetch(self, server_pointer: Pointer, weights,
                      base_version: int, epochs: int, link: Link, on_done,
@@ -242,7 +248,7 @@ class FLWorker:
             if self.profile.failed or not self.accepts(server_pointer):
                 self.busy = False
                 return
-            up = link.encode_up(self._train(weights, epochs))
+            up = link.encode_up(self._train(weights, epochs, base_version))
             assert up.wire_bytes == up_bytes, (up.wire_bytes, up_bytes)
             ticket = self.warehouse.issue_ticket(self.warehouse.put(up))
             self.busy = False
@@ -267,7 +273,7 @@ class FLWorker:
             if self.profile.failed or not self.accepts(server_pointer):
                 self.busy = False
                 return
-            up = link.encode_up(self._train(weights, epochs))
+            up = link.encode_up(self._train(weights, epochs, base_version))
             ticket = self.warehouse.issue_ticket(self.warehouse.put(up))
             self._inflight[server_pointer] = (ticket, up, link)
             t_up = self.true_t_transmit(up.wire_bytes)
