@@ -47,7 +47,8 @@ class FLWorker:
     # footprint (measured in benchmarks/scale_bench.py)
     __slots__ = ("worker_id", "address", "profile", "data", "train_fn",
                  "loop", "warehouse", "server_pointers", "_inflight",
-                 "_fetching", "_conv", "busy", "_per_batch_time")
+                 "_fetching", "_conv", "busy", "_per_batch_time",
+                 "_shard")
 
     def __init__(self, worker_id: str, *, profile: WorkerProfile,
                  data: Dict, train_fn: Callable, loop: EventLoop,
@@ -56,6 +57,9 @@ class FLWorker:
         self.address = f"worker://{worker_id}"
         self.profile = profile
         self.data = data
+        # device copy of data's (x, y), made at the first training: the
+        # shard never changes, so every later training reuses it
+        self._shard = None
         self.train_fn = train_fn       # (params, x, y, epochs) -> params
         self.loop = loop
         self.warehouse = DataWarehouse()
@@ -198,10 +202,15 @@ class FLWorker:
     def _train(self, weights, epochs: int, base_version: int):
         with trace.span("fl.train", worker=self.worker_id,
                         version=base_version):
-            if len(self.data["x"]):
-                return self.train_fn(weights, self.data["x"],
-                                     self.data["y"], epochs)
-            return weights          # no local data: echo (setup-3 zeros)
+            if not len(self.data["x"]):
+                return weights      # no local data: echo (setup-3 zeros)
+            if self._shard is None:
+                trace.count("shard_uploads")
+                self._shard = (trace.to_device(self.data["x"]),
+                               trace.to_device(self.data["y"]))
+            else:
+                trace.count("shard_hits")
+            return self.train_fn(weights, *self._shard, epochs)
 
     def _after_fetch(self, server_pointer: Pointer, weights,
                      base_version: int, epochs: int, link: Link, on_done,

@@ -115,9 +115,9 @@ def test_counters_match_the_schedule():
     """Sync top-k + int8 both ways with FedAdam, W workers, R rounds:
     one kept count per compressed encode (every uplink; every downlink
     but each worker's first, which ships the raw model), one evaluation
-    per version and one at start; the host copies are each training's
-    f32 images and int32 labels, each merge's W weights and the six
-    FedAdam scalars."""
+    per version and one at start; the host copies are each worker's f32
+    images and int32 labels once, at its first training, each merge's W
+    weights and the six FedAdam scalars."""
     setup = _setup()
     before = trace.counters()
     run_fl(setup, max_rounds=ROUNDS, server_opt="fedadam", **TOPK)
@@ -127,7 +127,9 @@ def test_counters_match_the_schedule():
     assert got["host_syncs"] == evals + ups + downs
     per_training = sum(s["x"].size * 4 + s["y"].size * 4
                        for s in setup.shards) // W
-    assert got["h2d_bytes"] == ROUNDS * (W * per_training + 4 * W + 6 * 4)
+    assert got["h2d_bytes"] == W * per_training + ROUNDS * (4 * W + 6 * 4)
+    assert got["shard_uploads"] == W
+    assert got["shard_hits"] == W * (ROUNDS - 1)
 
 
 def test_a_traced_run_is_bit_identical(traced):
