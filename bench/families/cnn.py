@@ -1,8 +1,10 @@
 """The thesis CNN family (arXiv:2211.07238, §4.2.4, Listing 4.1):
 conv5x5 -> ReLU -> maxpool2 -> conv5x5 -> ReLU -> maxpool2 -> dense.
 
-Three things live here, all the benchmark's own:
+Four things live here, all the benchmark's own:
 
+* the data: smooth random class templates, shifted and noised, as
+  ``dataset`` makes them from the seed;
 * the set-up: the weights made from the seed in one jitted call on the
   device, and the program's ``FLSetup`` around the program's own
   ``train_fn`` (``cnn_sgd_train`` through ``cnn_train_wrapper``) and
@@ -21,9 +23,62 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.traffic import seed_words
+
 KERNEL = 5       # conv window, both layers (Listing 4.1)
 POOL = 2
 F32 = jnp.float32
+# the program's local-training programs, by name in the device trace
+TRAIN_PROGRAMS = ("jit_cnn_sgd_train",)
+
+
+# --- data ---------------------------------------------------------------------
+# The benchmark's copy of the program's ``data/synth.py``
+# ``make_classification_dataset``; its shift loop is grouped by shift, the
+# values are the same.
+
+def _smooth(img: np.ndarray, passes: int = 2) -> np.ndarray:
+    for _ in range(passes):
+        img = (img + np.roll(img, 1, 0) + np.roll(img, -1, 0)
+               + np.roll(img, 1, 1) + np.roll(img, -1, 1)) / 5.0
+    return img
+
+
+def classification_dataset(n: int, *, hw: int, channels: int,
+                           n_classes: int, noise: float, max_shift: int = 1,
+                           seed: int = 0):
+    """(x (n, hw, hw, c) float32 in [0, 1], y (n,) int32): smooth random
+    class templates, small random translations, Gaussian noise."""
+    rng = np.random.RandomState(seed_words(seed))
+    if channels == 1:
+        t = rng.randn(n_classes, hw, hw, channels).astype(np.float32)
+        templates = _smooth(t.reshape(n_classes * channels, hw, hw)
+                            ).reshape(n_classes, hw, hw, channels)
+    else:
+        templates = rng.randn(n_classes, hw, hw, channels).astype(np.float32)
+        for i in range(n_classes):
+            for c in range(channels):
+                templates[i, :, :, c] = _smooth(templates[i, :, :, c])
+    y = rng.randint(0, n_classes, size=n).astype(np.int32)
+    x = templates[y]
+    sx = rng.randint(-max_shift, max_shift + 1, size=n)
+    sy = rng.randint(-max_shift, max_shift + 1, size=n)
+    for dx in range(-max_shift, max_shift + 1):
+        for dy in range(-max_shift, max_shift + 1):
+            sel = (sx == dx) & (sy == dy)
+            if sel.any():
+                x[sel] = np.roll(np.roll(x[sel], dx, 1), dy, 2)
+    x = x + noise * rng.randn(*x.shape).astype(np.float32)
+    x = (x - x.min()) / max(x.max() - x.min(), 1e-6)
+    return x.astype(np.float32), y
+
+
+def dataset(n: int, cfg: dict, spec: dict, seed: int):
+    """``n`` images of the configuration's shape and classes at the
+    traffic's ``noise``, and their labels, from ``seed``."""
+    return classification_dataset(
+        n, hw=cfg["image_hw"], channels=cfg["channels"],
+        n_classes=cfg["n_classes"], noise=float(spec["noise"]), seed=seed)
 
 
 # --- shapes and counts --------------------------------------------------------

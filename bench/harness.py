@@ -8,6 +8,18 @@ Everything particular to a cell is found by name: the configuration in
 ``bench/traffic/<traffic>.json``, the limits of the comparison in
 ``bench/limits/<workload>.json`` and each per-layer metric's reader in
 ``bench/metrics/<metric>.py``.
+
+A family module gives everything that depends on the model:
+``dataset(n, cfg, spec, seed)`` (``n`` items of ``(x, y)``),
+``init_weights(key, cfg)`` (a parameter tree of any nesting, on the
+device), ``build_setup(cfg, traffic, weights0)`` (the program's
+``FLSetup`` with its own ``train_fn`` and ``eval_fn``), the plain
+reference ``ref_train(cfg, params, x, y, epochs)`` and
+``ref_accuracy(params, x, y)``, the counts ``n_params(cfg)``,
+``train_flops(cfg, n, epochs)`` and ``train_bytes(cfg, n, epochs)``, and
+``TRAIN_PROGRAMS``, the names of its training programs in the device
+trace.  ``bench/calibrate.py`` also asks for ``control_train_fn(cfg)``
+and ``program_eval_fn(test_x, test_y)``.
 """
 from __future__ import annotations
 
@@ -120,11 +132,9 @@ class Recorder:
         self.train_s += time.perf_counter() - t0
         self.trains.append((len(x), int(epochs)))
         if self.capture is not None:
-            from .check import TrainRec
-            self.capture.trains.append(TrainRec(
-                at_version=len(self.server.history) - 1,
-                shard=self._shard(x), epochs=int(epochs), params_in=params,
-                params_out=out))
+            self.capture.add_training(len(self.server.history) - 1,
+                                      self._shard(x), int(epochs), params,
+                                      out)
         return out
 
     def eval(self, weights):
@@ -147,7 +157,7 @@ class Recorder:
             self.updates.append(hist[i].n_updates)
             if self.capture is not None:
                 from .check import VersionRec
-                self.capture.versions.append(VersionRec(
+                self.capture.add_version(VersionRec(
                     i, hist[i].n_updates, hist[i].accuracy,
                     self.server.weights))
         self._seen = n
@@ -180,7 +190,7 @@ def build(cell: Cell, seed: int, plant: Optional[Callable] = None) -> Built:
     from repro.core.experiment import build_experiment
 
     from . import traffic as traffic_mod
-    tr = traffic_mod.generate(cell.traffic, cell.config, seed)
+    tr = traffic_mod.generate(cell.traffic, cell.config, seed, cell.family)
     key = jax.random.fold_in(jax.random.PRNGKey(seed & 0xFFFFFFFF),
                              seed >> 32)
     weights0 = cell.family.init_weights(key, cell.config)
@@ -224,8 +234,10 @@ def warm_up(b: Built, log):
     need = max(int(spec["warmup"]["min_versions"]), n_check)
     quiet = int(spec["warmup"]["quiet_versions"])
     rec = b.rec
-    cap = rec.capture = Capture(versions=[VersionRec(
-        0, 0, b.server.history[0].accuracy, b.weights0)])
+    cap = rec.capture = Capture(
+        n_check, stale_bases=b.traffic.run_kw.get("mode") == "async",
+        versions=[VersionRec(0, 0, b.server.history[0].accuracy,
+                             b.weights0)])
     marks = [log.events]                 # compile events at each version
 
     def done() -> bool:
@@ -240,6 +252,7 @@ def warm_up(b: Built, log):
     b.server.start()
     b.loop.run(break_when=done)
     rec.capture = None
+    cap.close()
     if b.server.done or b.loop.exhausted:
         raise RuntimeError("the federation ended during warm-up")
     return cap
@@ -275,6 +288,28 @@ def measure(b: Built, seconds: float, log) -> dict:
             "start": start}
 
 
+def traced_measure(b: Built, seconds: float, log,
+                   trace_dir: Path) -> tuple:
+    """``measure`` under the profiler, inside a ``bench_window`` span:
+    the window's record, with the difference of the program's counters
+    (``repro.runtime.trace.counters``) over it under ``"counters"``, and
+    the plain record of the trace (``tracing.load``)."""
+    import jax
+    from repro.runtime.trace import counters
+
+    from . import tracing
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    jax.profiler.start_trace(str(trace_dir))
+    c0 = counters()
+    with jax.profiler.TraceAnnotation("bench_window"):
+        win = measure(b, seconds, log)
+    win["counters"] = {k: v - c0.get(k, 0) for k, v in counters().items()}
+    jax.profiler.stop_trace()
+    rec = tracing.load(str(trace_dir))
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    return win, rec
+
+
 @dataclass
 class Result:
     correct: bool
@@ -307,8 +342,6 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     evaluation (the control, the faults of the tests; see ``build``)."""
     cell = load_cell(workload, overrides)
     device = gate(cell.chips)
-    import jax
-
     from . import check, device as device_mod, tracing
     if compile_cache:
         from repro.runtime.compile_cache import enable_compile_cache
@@ -324,11 +357,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
 
     breakdown, metrics = None, {}
     if trace:
-        shutil.rmtree(TRACE_DIR, ignore_errors=True)
-        jax.profiler.start_trace(str(TRACE_DIR))
-        with jax.profiler.TraceAnnotation("bench_window"):
-            win = measure(b, min(seconds, TRACE_SECONDS), log)
-        jax.profiler.stop_trace()
+        win, trace_rec = traced_measure(b, min(seconds, TRACE_SECONDS), log,
+                                        TRACE_DIR)
     else:
         win = measure(b, seconds, log)
     device = dict(device,
@@ -337,8 +367,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
           f"versions, {win['updates']} updates, {win['window_compiles']} "
           "compiles inside the window", file=sys.stderr, flush=True)
     if trace:
-        tr = tracing.reduce(tracing.load(TRACE_DIR))
-        shutil.rmtree(TRACE_DIR, ignore_errors=True)
+        tr = tracing.reduce(trace_rec)
         metrics = per_layer(Context(
             cell=cell, win=win, rec=b.rec, trace=tr,
             n_params=cell.family.n_params(cell.config),
@@ -372,6 +401,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
 def reference_numbers(cell: Cell, cap, tr) -> Dict[str, float]:
     """The compared numbers of the recorded first versions; where the
     reference cannot follow the run's schedule, none (not correct)."""
+    import jax
     import jax.numpy as jnp
 
     from . import check
@@ -382,8 +412,8 @@ def reference_numbers(cell: Cell, cap, tr) -> Dict[str, float]:
             n_versions=int(cell.traffic["check_versions"]),
             train=lambda p, x, y, e: fam.ref_train(cell.config, p, x, y, e),
             accuracy=lambda p: fam.ref_accuracy(p, tr.test_x, tr.test_y),
-            to_device=lambda t: {k: jnp.asarray(v, jnp.float32)
-                                 for k, v in t.items()})
+            to_device=lambda t: jax.tree.map(
+                lambda v: jnp.asarray(v, jnp.float32), t))
     except check.CheckError as e:
         print(f"bench: the reference cannot follow the run: {e}",
               file=sys.stderr, flush=True)

@@ -2,8 +2,8 @@
 program's ``fl.sync`` reads (a device value turned into a host number,
 such as the top-k encode's kept count), waiting on the work queued
 before it."""
-from bench import spans
+from bench import tracing
 
 
 def read(ctx):
-    return spans.span_ms(ctx, ("fl.sync",), "updates")
+    return tracing.span_ms(ctx, ("fl.sync",), "updates")

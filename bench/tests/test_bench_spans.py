@@ -1,7 +1,8 @@
-"""The reduction of the program's spans (``bench/spans.py``): self time
+"""The reduction of the program's spans (``bench/tracing.py``): self time
 and idle time by innermost span, by hand on a small made-up record, the
 sweep against a plain scan, the span and counter readers with and without what
-they read, and a record cut from a TPU v5e trace of the top-k cell."""
+they read, and a record cut from a TPU v5e trace of the top-k cell
+(``bench/spans.py --record``)."""
 import json
 import random
 from pathlib import Path
@@ -13,6 +14,10 @@ from bench import harness, spans, tracing
 
 FIXTURE = Path(__file__).resolve().parent / "fixtures" / "trace_spans.json"
 METRICS = Path(__file__).resolve().parents[1] / "metrics"
+# the readers of the program's spans and counters
+READERS = ("codec_host_ms_per_update", "sync_wait_ms_per_update",
+           "merge_host_ms_per_version", "control_host_ms_per_update",
+           "host_syncs_per_update", "h2d_kb_per_update")
 
 # window 0..200 ns on one host line; device ops 12-30, 60-65, 112-118,
 # 140-145.  fl.train 0-40 holds train_fn 5-35; fl.encode_up 40-80 holds
@@ -34,8 +39,12 @@ HAND = {
 }
 
 
+def _without_spans(rec):
+    return {k: v for k, v in rec.items() if k != "spans"}
+
+
 def test_self_time_and_idle_by_span_by_hand():
-    r = spans.reduce(HAND)
+    r = tracing.reduce(HAND)
     ns = {k: v * 1e9 for k, v in r.self_s.items()}
     assert ns == pytest.approx({"fl.train": 10, "fl.encode_up": 20,
                                 "fl.sync": 20, "fl.respond": 25,
@@ -52,8 +61,8 @@ def test_self_time_and_idle_by_span_by_hand():
                        "fl.decode_up": 10, "fl.merge": 14, "fl.eval": 4,
                        "eval_fn": 6})
     assert len(r.breakdown()["idle_gaps"]) == 10
-    # the device side is tracing.reduce's, unchanged
-    base = tracing.reduce(HAND)
+    # the device side does not depend on the spans
+    base = tracing.reduce(_without_spans(HAND))
     assert (r.busy_s, r.window_s, r.module_s) == (
         base.busy_s, base.window_s, base.module_s)
     assert sum(s for _, s in r.gaps) == pytest.approx(
@@ -92,7 +101,7 @@ def test_sweep_matches_a_plain_scan():
                     else "loop"
                 want[lab] = want.get(lab, 0.0) + v - u
         got = {}
-        for lab, ns in spans.label_gaps(gaps, sp):
+        for lab, ns in tracing.label_gaps(gaps, sp):
             got[lab] = got.get(lab, 0.0) + ns
         assert got == pytest.approx(want)
 
@@ -102,11 +111,11 @@ def _read(name, ctx):
 
 
 def test_readers():
-    r = spans.reduce(HAND)
+    r = tracing.reduce(HAND)
     win = {"updates": 2, "versions": 1,
            "counters": {"host_syncs": 3, "h2d_bytes": 3_768_000}}
     ctx = SimpleNamespace(trace=r, win=win)
-    got = {n: _read(n, ctx) for n in spans.METRICS}
+    got = {n: _read(n, ctx) for n in READERS}
     assert got == pytest.approx({
         "codec_host_ms_per_update": 1e-6 * (20 + 10) / 2,
         "sync_wait_ms_per_update": 1e-6 * 20 / 2,
@@ -117,14 +126,15 @@ def test_readers():
 
 
 def test_readers_find_nothing_in_a_program_without_spans():
-    """The parent's program: no ``fl.`` spans, no counters; each reader
-    returns None and the line leaves the metric out."""
-    rec = {k: v for k, v in HAND.items() if k != "spans"}
-    for trace in (tracing.reduce(rec), spans.reduce(rec)):
-        ctx = SimpleNamespace(trace=trace, win={"updates": 2,
-                                                "versions": 1})
-        assert {n: _read(n, ctx) for n in spans.METRICS} == dict.fromkeys(
-            spans.METRICS)
+    """A program without ``fl.`` spans or counters, its record with or
+    without the benchmark's own spans: each reader returns None and the
+    line leaves the metric out."""
+    bench_only = dict(HAND, spans=[s for s in HAND["spans"]
+                                   if not s[0].startswith("fl.")])
+    for rec in (_without_spans(HAND), bench_only):
+        ctx = SimpleNamespace(trace=tracing.reduce(rec),
+                              win={"updates": 2, "versions": 1})
+        assert {n: _read(n, ctx) for n in READERS} == dict.fromkeys(READERS)
 
 
 def test_cut_keeps_what_overlaps_the_start():
@@ -141,7 +151,7 @@ def test_recorded_trace():
     the device side reduces as ``tracing`` has it, the spans nest on the
     main thread, and the idle time is named by the program's spans."""
     rec = json.loads(FIXTURE.read_text())
-    r, base = spans.reduce(rec), tracing.reduce(rec)
+    r, base = tracing.reduce(rec), tracing.reduce(_without_spans(rec))
     assert (r.busy_s, r.module_s) == (base.busy_s, base.module_s)
     assert sum(s for _, s in r.gaps) == pytest.approx(r.window_s - r.busy_s)
     assert {"fl.train", "fl.encode_up", "fl.sync"} <= set(r.count)

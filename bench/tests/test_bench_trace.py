@@ -11,14 +11,15 @@ from bench import tracing
 FIXTURE = Path(__file__).resolve().parent / "fixtures" / "trace_small.json"
 
 # window 0..100 ns; device ops 10-30, 20-40 (overlap), 60-70;
-# host: train_fn 0-50, eval_fn 65-90
+# host spans: train_fn 0-50, eval_fn 65-90
 HAND = {
     "devices": {"/device:TPU:0": {
         "ops": [["a", 10, 20], ["b", 20, 20], ["c", 60, 10], ["d", 95, 10]],
         "modules": [["jit_cnn_sgd_train(1)", 10, 30],
                     ["jit__weighted_sum(2)", 60, 10]]}},
-    "host": [["bench_window", 0, 100], ["train_fn", 0, 50],
-             ["eval_fn", 65, 25]],
+    "host": [["bench_window", 0, 100]],
+    "spans": [["train_fn", "/host:CPU#0", 0, 50],
+              ["eval_fn", "/host:CPU#0", 65, 25]],
 }
 
 
@@ -37,13 +38,32 @@ def test_reduce_by_hand():
     assert r.idle_share == pytest.approx(0.55)
     assert r.time_of(["cnn_sgd_train"]) == pytest.approx(30e-9)
     assert r.calls_of(["_weighted_sum"]) == 1
-    # gap 0-10 is in train_fn; 40-60 (mid 50) after it, in the loop;
-    # 70-95 (mid 82.5) in eval_fn
+    # gaps cut where host spans end: 0-10 and 40-50 in train_fn, 50-60
+    # and 90-95 in the loop, 70-90 in eval_fn
     assert r.idle_by_label() == pytest.approx(
-        {"train_fn": 10e-9, "loop": 20e-9, "eval_fn": 25e-9})
+        {"train_fn": 20e-9, "loop": 15e-9, "eval_fn": 20e-9})
     bd = r.breakdown()
     assert bd["device_ops"][0][0] == "jit_cnn_sgd_train(1)"
     assert len(bd["idle_gaps"]) <= 10
+
+
+def test_breakdown_keeps_ten_entries_past_ten_labels():
+    """Fourteen labels and programs: each list keeps the ten largest."""
+    r = tracing.Reduced(
+        window_s=1.0, busy_s=0.5,
+        module_s={f"jit_p{i}": i * 1e-3 for i in range(14)},
+        gaps=[(f"fl.s{i}", i * 1e-3) for i in range(14)])
+    bd = r.breakdown()
+    assert [n for n, _ in bd["device_ops"]] == [f"jit_p{i}"
+                                                for i in range(13, 3, -1)]
+    assert [n for n, _ in bd["idle_gaps"]] == [f"all fl.s{i}"
+                                               for i in range(13, 3, -1)]
+    few = tracing.Reduced(window_s=1.0, busy_s=0.5,
+                          gaps=[("loop", 3e-3), ("loop", 2e-3),
+                                ("train_fn", 1e-3)]).breakdown()
+    assert few["idle_gaps"] == [["all loop", 5e-3], ["all train_fn", 1e-3],
+                                ["gap loop", 3e-3], ["gap loop", 2e-3],
+                                ["gap train_fn", 1e-3]]
 
 
 def _naive(rec):

@@ -3,12 +3,13 @@
 module turns it and ``--seed`` into the data, the worker profiles and the
 keyword arguments of ``build_experiment``.
 
-The data and profile generators are the benchmark's own copies of the
-program's (``data/synth.py`` ``make_classification_dataset`` with
-``federated_split``, and ``core/experiment.py``
+What the data is belongs to the model: the configuration's family
+(``bench/families/<family>.py``) makes ``n`` items of ``(x, y)`` from the
+seed with its ``dataset``, and this module deals them out.  The split
+and profile generators are the benchmark's own copies of the program's
+(``data/synth.py`` ``federated_split``, and ``core/experiment.py``
 ``heterogeneous_profiles``), so a later change to those cannot move what
-the benchmark feeds the system.  The shift loop of the data generator is
-grouped by shift; the values are the same.
+the benchmark feeds the system.
 """
 from __future__ import annotations
 
@@ -46,42 +47,6 @@ def allocation(traffic: dict) -> List[int]:
     return [int(traffic["batches_each"])] * int(traffic["workers"])
 
 
-def _smooth(img: np.ndarray, passes: int = 2) -> np.ndarray:
-    for _ in range(passes):
-        img = (img + np.roll(img, 1, 0) + np.roll(img, -1, 0)
-               + np.roll(img, 1, 1) + np.roll(img, -1, 1)) / 5.0
-    return img
-
-
-def classification_dataset(n: int, *, hw: int, channels: int,
-                           n_classes: int, noise: float, max_shift: int = 1,
-                           seed: int = 0):
-    """(x (n, hw, hw, c) float32 in [0, 1], y (n,) int32): smooth random
-    class templates, small random translations, Gaussian noise."""
-    rng = np.random.RandomState(seed_words(seed))
-    if channels == 1:
-        t = rng.randn(n_classes, hw, hw, channels).astype(np.float32)
-        templates = _smooth(t.reshape(n_classes * channels, hw, hw)
-                            ).reshape(n_classes, hw, hw, channels)
-    else:
-        templates = rng.randn(n_classes, hw, hw, channels).astype(np.float32)
-        for i in range(n_classes):
-            for c in range(channels):
-                templates[i, :, :, c] = _smooth(templates[i, :, :, c])
-    y = rng.randint(0, n_classes, size=n).astype(np.int32)
-    x = templates[y]
-    sx = rng.randint(-max_shift, max_shift + 1, size=n)
-    sy = rng.randint(-max_shift, max_shift + 1, size=n)
-    for dx in range(-max_shift, max_shift + 1):
-        for dy in range(-max_shift, max_shift + 1):
-            sel = (sx == dx) & (sy == dy)
-            if sel.any():
-                x[sel] = np.roll(np.roll(x[sel], dx, 1), dy, 2)
-    x = x + noise * rng.randn(*x.shape).astype(np.float32)
-    x = (x - x.min()) / max(x.max() - x.min(), 1e-6)
-    return x.astype(np.float32), y
-
-
 def split(x: np.ndarray, y: np.ndarray, batches: Sequence[int],
           batch_size: int, seed: int) -> List[dict]:
     """IID shards: a seeded permutation dealt out by each worker's
@@ -117,17 +82,15 @@ class Traffic:
     run_kw: dict
 
 
-def generate(spec: dict, cfg: dict, seed: int) -> Traffic:
+def generate(spec: dict, cfg: dict, seed: int, family) -> Traffic:
     """Data, profiles and run settings of ``spec`` for the model
-    configuration ``cfg`` (its input shape and classes).  ``seed`` makes
-    the data; the schedule (allocation, profiles, cohort draws) is the
-    traffic file's, the same for every seed."""
+    configuration ``cfg`` of ``family``, whose ``dataset(n, cfg, spec,
+    seed)`` makes the items.  ``seed`` makes the data; the schedule
+    (allocation, profiles, cohort draws) is the traffic file's, the same
+    for every seed."""
     batches = allocation(spec)
     bs, n_test = int(spec["batch_size"]), int(spec["n_test"])
-    x, y = classification_dataset(
-        sum(batches) * bs + n_test, hw=cfg["image_hw"],
-        channels=cfg["channels"], n_classes=cfg["n_classes"],
-        noise=float(spec["noise"]), seed=seed)
+    x, y = family.dataset(sum(batches) * bs + n_test, cfg, spec, seed)
     shards = split(x[:-n_test], y[:-n_test], batches, bs, seed + 1)
     return Traffic(spec=spec, shards=shards,
                    test_x=x[-n_test:], test_y=y[-n_test:],
