@@ -19,9 +19,9 @@ selection/budget state, warehouse contents and ticket tables, history
 counters, and the event-loop clock plus every pending timer as
 ``(time, seq)`` records.
 
-It **re-derives** (never serializes): packed server mirrors and
-per-round pack caches (``_server_flat``/``_down_vec`` — bitwise-same
-repacks of the restored weights), population views, tuner bandwidth
+It **re-derives** (never serializes): per-round pack caches
+(``_down_vec`` — bitwise-same repacks of the restored weights),
+population views, tuner bandwidth
 closures, jitted functions, and link objects themselves.
 
 In-flight payloads on *lossy* links are **cancelled-with-credit at

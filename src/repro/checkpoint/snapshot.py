@@ -342,9 +342,6 @@ def _restore_flat(fl, img: Optional[dict]) -> None:
     fl._free = list(img["free"])
     fl._next_row = img["next_row"]
     fl._dirty = set(img["dirty"])
-    # packed server mirror: re-derived (bitwise-same repack)
-    fl._server_flat = None
-    fl._server_tree = None
 
 
 # --- server capture/restore ---

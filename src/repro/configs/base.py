@@ -36,6 +36,10 @@ class ModelConfig:
     rope_theta: float = 10_000.0
     window: int = 0                  # sliding-window size; 0 = full attention
     alt_local_global: bool = False   # gemma2: even layers local(window), odd global
+    layer_windows: Tuple[int, ...] = ()   # window of each layer of a repeating period (0 = full)
+    # YaRN of the full (window 0) layers: (factor, original_max_positions,
+    # beta_fast, beta_slow, attention_factor); None = plain RoPE everywhere
+    rope_yarn: Optional[Tuple[float, int, float, float, float]] = None
     attn_softcap: float = 0.0        # gemma2 logit soft-capping (50.0)
     final_softcap: float = 0.0       # gemma2 final-logit soft-capping (30.0)
     post_block_norm: bool = False    # gemma2 sandwich norms
@@ -43,6 +47,11 @@ class ModelConfig:
     n_experts: int = 0
     top_k: int = 0
     capacity_factor: float = 1.25
+    # expert-parallel share: this chip holds experts [expert_offset,
+    # expert_offset + experts_held) of the router's n_experts and routes
+    # without capacity (dropless); 0 = all experts, capacity dispatch
+    experts_held: int = 0
+    expert_offset: int = 0
     # --- ssm / hybrid ---
     block_type: str = "attn"         # attn | rwkv6 | mamba2
     ssm_state: int = 0               # mamba2 state dim
@@ -52,6 +61,7 @@ class ModelConfig:
     shared_attn_every: int = 0       # zamba2: one shared attn block per N ssm blocks
     # --- frontends (vlm / audio) ---
     embeds_input: bool = False       # model consumes precomputed embeddings (stub frontend)
+    tie_embeddings: bool = True      # False: an lm_head table of its own
     # --- numerics / memory ---
     loss_chunk: int = 512            # sequence chunk for vocab loss
     remat: bool = True
@@ -71,6 +81,21 @@ class ModelConfig:
     def is_moe(self) -> bool:
         return self.n_experts > 0
 
+    @property
+    def n_experts_here(self) -> int:
+        """Experts whose weights this model holds."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def period(self) -> Tuple[int, ...]:
+        """Windows of the layer period the attention stack scans over (0 =
+        full attention); () for a uniform stack."""
+        if self.layer_windows:
+            return self.layer_windows
+        if self.alt_local_global:
+            return (self.window, 0)
+        return ()
+
     def kv_cache_len(self, seq_len: int) -> int:
         """Per-layer KV-cache length for decode at context ``seq_len``.
 
@@ -78,20 +103,22 @@ class ModelConfig:
         gemma2's alternating stack still contains global layers, so it cannot
         bound the cache.
         """
-        if self.window and not self.alt_local_global:
+        if self.window and not self.period:
             return min(seq_len, self.window)
         return seq_len
 
     def n_params(self) -> int:
-        """Analytic parameter count (embedding + blocks + head)."""
+        """Analytic parameter count (embedding + blocks + final norm +
+        head; the held experts of an expert-parallel share)."""
         d, hd = self.d_model, self.hd
-        n = self.vocab_size * d                       # embed (tied head)
+        n = self.vocab_size * d * (1 if self.tie_embeddings else 2) + d
         per_attn = d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd + self.n_heads * hd * d
         if self.block_type == "attn":
             per_ffn = 3 * d * self.d_ff
             if self.is_moe:
-                per_ffn = per_ffn * self.n_experts + d * self.n_experts
-            n += self.n_layers * (per_attn + per_ffn + 2 * d)
+                per_ffn = per_ffn * self.n_experts_here + d * self.n_experts
+            norms = (4 if self.post_block_norm else 2) * d
+            n += self.n_layers * (per_attn + per_ffn + norms)
         elif self.block_type == "rwkv6":
             # time-mix (r,k,v,g,o + decay lora) + channel-mix
             per = 5 * d * d + 2 * d * self.d_ff + 6 * d * 32 * 2 + 4 * d
@@ -105,12 +132,15 @@ class ModelConfig:
         return n
 
     def n_active_params(self) -> int:
-        """Active params per token (MoE: top_k of n_experts)."""
+        """Active params per token (MoE: top_k of n_experts; a share's
+        held experts serve top_k * experts_held / n_experts of them on
+        average)."""
         if not self.is_moe:
             return self.n_params()
-        d = self.d_model
-        dense = self.n_params() - self.n_layers * (3 * d * self.d_ff * self.n_experts)
-        return dense + self.n_layers * 3 * d * self.d_ff * self.top_k
+        d, e_here = self.d_model, self.n_experts_here
+        dense = self.n_params() - self.n_layers * (3 * d * self.d_ff * e_here)
+        active = self.top_k * e_here / self.n_experts
+        return dense + int(self.n_layers * 3 * d * self.d_ff * active)
 
     def n_shared_attn_applications(self) -> int:
         if self.shared_attn_every <= 0:
@@ -124,9 +154,10 @@ class ModelConfig:
 def _registry():
     from . import (gemma2_2b, yi_9b, deepseek_67b, starcoder2_15b, mixtral_8x22b,
                    phi35_moe, rwkv6_3b, zamba2_7b, internvl2_26b, musicgen_medium,
-                   paper_cnn)
+                   mellum2_12b_a2p5b, paper_cnn)
     mods = [gemma2_2b, yi_9b, deepseek_67b, starcoder2_15b, mixtral_8x22b,
-            phi35_moe, rwkv6_3b, zamba2_7b, internvl2_26b, musicgen_medium]
+            phi35_moe, rwkv6_3b, zamba2_7b, internvl2_26b, musicgen_medium,
+            mellum2_12b_a2p5b]
     return {m.CONFIG.name: m for m in mods}
 
 

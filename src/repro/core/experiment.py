@@ -16,8 +16,9 @@ import numpy as np
 from repro.configs.paper_cnn import CNNConfig, FAST_MNIST_CNN, MNIST_CNN
 from repro.data.synth import (federated_split, make_classification_dataset,
                               partition_split)
-from repro.models import cnn
+from repro.models import cnn, transformer
 from repro.parallel import sharding as psharding
+from repro.runtime import trace
 from repro.runtime.trace import to_device
 
 from .estimator import TimeEstimator, WorkerProfile
@@ -142,6 +143,31 @@ def make_setup(batches_per_worker: Sequence[int], *,
 def cnn_train_wrapper(params, x, y, epochs, lr=0.01):
     return cnn.cnn_sgd_train(params, to_device(x), to_device(y),
                              lr=lr, epochs=int(epochs))
+
+
+def lm_train_wrapper(params, x, y, epochs, lr, cfg):
+    """A language model's local training (``transformer.lm_sgd_train``)
+    over a shard of token ids ``x`` and next-token labels ``y``.  It
+    returns once the trained weights are ready: a training holds
+    gigabytes of temporaries, and the loop dispatches the round's next
+    training (and the server packs this one's output) at once, so
+    without the wait their memory would be claimed beside them."""
+    trace.count("train_tokens", int(x.size) * int(epochs))
+    out = transformer.lm_sgd_train(params, to_device(x), to_device(y),
+                                   epochs=int(epochs), lr=lr, cfg=cfg)
+    with trace.span("fl.sync", site="lm_train"):
+        trace.count("host_syncs")
+        return jax.block_until_ready(out)
+
+
+def lm_accuracy(params, tokens, labels, cfg, block: int = 1) -> float:
+    """Next-token top-1 accuracy over (n, S) test ids, ``block`` sequences
+    at a time."""
+    hits = 0
+    for i in range(0, len(tokens), block):
+        hits += transformer.lm_hits(params, tokens[i:i + block],
+                                    labels[i:i + block], cfg)
+    return float(hits) / labels.size
 
 
 def mlp_train_wrapper(params, x, y, epochs, lr=0.1):

@@ -365,8 +365,6 @@ class FlatServerState:
         # result in _finish (one fused elementwise pass) before unpack
         self.server_opt = None
         self._rows: Optional[jnp.ndarray] = None
-        self._server_flat: Optional[jnp.ndarray] = None
-        self._server_tree: Optional[object] = None   # strong ref: mirror key
         # --- cohort row window (win_claim/win_write/win_release) ---
         # recycled rows, a min-heap: claims reuse the LOWEST free index,
         # so a sync round's arrivals land in rows [0..n) in arrival order
@@ -374,6 +372,10 @@ class FlatServerState:
         # windowed merge bit-identical at cohort=W
         self._free: list = []
         self._next_row = 0            # high-water mark of ever-claimed rows
+        # rows of the window's first allocation: the server sets its cohort,
+        # so a round's rows fit without the 2x growth (a row of a large
+        # model is a whole model's bytes)
+        self.window_rows = 8
         # released-but-not-yet-zeroed rows: zeroing is deferred and batched
         # into one scatter right before the next merge (a stale non-finite
         # value would turn 0 * inf into NaN inside the fused contraction)
@@ -421,19 +423,17 @@ class FlatServerState:
         self._rows = new
 
     def _server_buffer(self, server_tree) -> jnp.ndarray:
-        if (self._server_flat is None
-                or self._server_tree is not server_tree):
-            self._server_flat = self.bundle.pack(server_tree)
-        buf = self._server_flat
-        self._server_flat = None         # donated to the merge below
-        return buf
+        """The server model packed, for the merges that read it (alpha < 1,
+        deltas).  Packed when needed and donated to the merge, not kept
+        beside the tree: at 10^8 parameters a standing packed copy of the
+        model is gigabytes of device memory."""
+        return self.bundle.pack(server_tree)
 
     def merge(self, server_tree, update_trees: Sequence,
               weights: Sequence[float], alpha: float = 1.0):
         """Fused ``(1-alpha)*server + alpha * sum_i w_hat_i * x_i``.
 
-        Returns the merged pytree (original dtypes); the packed result is
-        cached so next round's merge skips re-packing the server model.
+        Returns the merged pytree (original dtypes).
         """
         n = len(update_trees)
         self._ensure_capacity(n)
@@ -471,13 +471,12 @@ class FlatServerState:
 
     def _finish(self, server_tree, merged):
         """Shared merge epilogue: optional server-optimizer pass (in
-        packed space — the whole point of the flat substrate), unpack,
-        refresh the packed mirror.  With ``server_opt=None`` this is
-        byte-for-byte the old tail (golden-pinned)."""
+        packed space — the whole point of the flat substrate), unpack.
+        With ``server_opt=None`` this is byte-for-byte the old tail
+        (golden-pinned)."""
         if self.server_opt is not None:
             merged = self.server_opt.step_vec(self, server_tree, merged)
         out = self.bundle.unpack(merged)
-        self._server_flat, self._server_tree = merged, out
         if self.server_opt is not None:
             self.server_opt.note_result(merged, out)
         return out
@@ -505,7 +504,8 @@ class FlatServerState:
             # geometric growth: per-claim exact growth would copy the
             # whole buffer O(window) times (extra capacity is harmless —
             # zero rows at zero weight never change the merge result)
-            self._ensure_capacity(max(row + 1, 2 * self.capacity, 8))
+            self._ensure_capacity(max(row + 1, 2 * self.capacity,
+                                      self.window_rows))
         return row
 
     def win_write(self, row: int, vec) -> None:

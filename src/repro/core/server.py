@@ -144,6 +144,8 @@ class AggregationServer:
         self._profiles_view = None          # cached population view
         self._row_of: Dict[str, int] = {}   # worker -> claimed window row
         self._window = cohort is not None and self._use_vec
+        if self._window:
+            self._flat.window_rows = cohort
         self._inflight_w: set = set()       # dispatched, response pending
 
         # hierarchical topology (core/topology.py): when set, this server is

@@ -102,9 +102,8 @@ class ServerOpt:
     """Base: packed-vector optimizer state bound lazily to the merge's
     :class:`~repro.core.flatbuf.ParamBundle` at the first step.
 
-    ``prev`` (the pre-merge packed server) is tracked by tree identity,
-    mirroring ``FlatServerState``'s own packed-mirror discipline: the
-    post-step vector becomes next round's ``prev`` unless the server
+    ``prev`` (the pre-merge packed server) is tracked by tree identity:
+    the post-step vector becomes next round's ``prev`` unless the server
     model was replaced externally (checkpoint restore, root failover) —
     then the identity check fails and the anchor re-packs from the tree.
     """
@@ -139,9 +138,8 @@ class ServerOpt:
             return merged
         if (self._prev_tree is not server_tree or self._prev_vec is None
                 or self._prev_vec.is_deleted()):
-            # re-pack (bitwise-same for f32): first step, external model
-            # replacement (restore / failover), or the cached anchor was
-            # donated into an alpha<1 fused_merge as the server mirror
+            # re-pack (bitwise-same for f32): first step, or external
+            # model replacement (restore / failover)
             self._prev_vec = flat.bundle.pack(server_tree)
         prev = self._prev_vec
         if self._m is None:

@@ -1049,7 +1049,7 @@ class Transport:
         self.closed = False
         # one packed copy of the current server model per dispatch round:
         # every selected worker's encode_down shares it (keyed on tree
-        # identity, the FlatServerState mirror pattern)
+        # identity)
         self._down_tree = None
         self._down_vec: Optional[jnp.ndarray] = None
 

@@ -250,6 +250,10 @@ class FLWorker:
         up_bytes = rec["up_bytes"]
 
         def _finish():
+            # the event has fired: drop rec -> event -> _finish -> rec, a
+            # cycle that would keep this training's input model alive
+            # until the cyclic garbage collector runs
+            rec["ev"] = None
             if self._conv.get(server_pointer) is rec:
                 self._conv.pop(server_pointer)
             # died mid-training, or the server dropped this worker
@@ -276,6 +280,7 @@ class FLWorker:
         base_version, t_train = rec["base_version"], rec["t_train"]
 
         def _train_then_send():
+            rec["ev"] = None             # fired: break the cycle (_finish)
             if self._conv.get(server_pointer) is rec:
                 self._conv.pop(server_pointer)
             # died mid-training, or the server dropped this worker
@@ -311,6 +316,7 @@ class FLWorker:
                 if entry is None:
                     self.busy = False
                 return
+            rec["ev"] = None         # fired: break the cycle (_finish)
             self._inflight.pop(server_pointer)
             if self._conv.get(server_pointer) is rec:
                 self._conv.pop(server_pointer)

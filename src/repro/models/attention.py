@@ -17,6 +17,7 @@ clean mesh sharding.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -71,63 +72,86 @@ def naive_attention(q, k, v, *, causal=True, window=0, softcap_val=0.0,
     return out
 
 
+def _block_scores(qb, kb_r, qpos, kp_, *, scale, causal, window,
+                  softcap_val):
+    """Masked scores (B,H,qb,kb) of a query block against a KV block, and
+    the soft-cap's tanh (None without one)."""
+    s = jnp.einsum("bqhd,bkhd->bhqk", qb, kb_r,
+                   preferred_element_type=jnp.float32) * scale
+    t = None
+    if softcap_val:
+        t = jnp.tanh(s / softcap_val)
+        s = softcap_val * t
+    msk = jnp.ones((qb.shape[1], kb_r.shape[1]), bool)
+    if causal:
+        msk &= qpos[:, None] >= kp_[None, :]
+    if window:
+        msk &= (qpos[:, None] - kp_[None, :]) < window
+    # an additive (qb,kb) bias: a mask broadcast over batch and heads is
+    # what XLA would hoist out of the KV scan and keep for every step
+    return s + jnp.where(msk, 0.0, NEG_INF)[None, None], t
+
+
 def mha_chunked(q, k, v, *, causal=True, window=0, softcap_val=0.0,
                 q_block=512, kv_block=512, q_offset=0):
     """Blockwise attention with online softmax; never materialises (S,T).
 
-    Python loop over query blocks (static bounds) -> for each, ``lax.scan``
-    over the statically-required KV blocks only. FLOPs therefore track the
-    causal/windowed band instead of the full square.
+    A scan over query blocks -> for each, a loop over the KV blocks its
+    causal/windowed mask admits (bounds fixed per block at trace time), so
+    FLOPs track the band instead of the full square.  The backward pass is
+    flash attention's: it keeps only q, k, v, the output and each query's
+    log-sum-exp, and recomputes the probabilities block by block.
     """
     B, S, H, hd = q.shape
     T = k.shape[1]
     q_block = min(q_block, S)
     kv_block = min(kv_block, T)
     assert S % q_block == 0 and T % kv_block == 0, (S, q_block, T, kv_block)
-    scale = 1.0 / math.sqrt(hd)
-    kpos_all = jnp.arange(T, dtype=jnp.int32)
+    return _mha_chunked(q, k, v, causal, window, softcap_val, q_block,
+                        kv_block, q_offset)
 
-    out_blocks = []
+
+def _kv_blocks(S, T, *, causal, window, q_block, kv_block, q_offset):
+    """(query blocks, 3) int32: each query block's start and the range of
+    KV blocks [lo, hi) that its causal/windowed mask admits."""
+    rows = []
     for qs in range(0, S, q_block):
         q_abs_lo, q_abs_hi = q_offset + qs, q_offset + qs + q_block
-        lo = 0
-        hi = T
-        if causal:
-            hi = min(T, q_abs_hi)
-        if window:
-            lo = max(0, q_abs_lo - window + 1)
-        lo = (lo // kv_block) * kv_block
-        hi = -(-hi // kv_block) * kv_block
-        hi = min(hi, T)
-        nblk = (hi - lo) // kv_block
-        qb = q[:, qs:qs + q_block]                      # (B,qb,H,hd)
-        qpos = (jnp.arange(q_block, dtype=jnp.int32) + q_abs_lo)
+        lo = max(0, q_abs_lo - window + 1) if window else 0
+        hi = min(T, q_abs_hi) if causal else T
+        rows.append((qs, lo // kv_block, min(-(-hi // kv_block),
+                                              T // kv_block)))
+    return jnp.asarray(rows, jnp.int32)
 
-        m0 = jnp.full((B, H, q_block), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((B, H, q_block), jnp.float32)
-        a0 = jnp.zeros((B, H, q_block, hd), jnp.float32)
 
-        def body(carry, bi):
+def _mha_forward(q, k, v, causal, window, softcap_val, q_block, kv_block,
+                 q_offset):
+    """The attention output and each query block's log-sum-exp of its
+    scores, (query blocks, B, H, qb)."""
+    B, S, H, hd = q.shape
+    T = k.shape[1]
+    masks = dict(scale=1.0 / math.sqrt(hd), causal=causal, window=window,
+                 softcap_val=softcap_val)
+    blocks = _kv_blocks(S, T, causal=causal, window=window, q_block=q_block,
+                        kv_block=kv_block, q_offset=q_offset)
+
+    def qblock(_, b):
+        qs, lo, hi = b[0], b[1], b[2]
+        qb = jax.lax.dynamic_slice_in_dim(q, qs, q_block, axis=1)
+        qpos = jnp.arange(q_block, dtype=jnp.int32) + q_offset + qs
+
+        def body(bi, carry):
             m, l, acc = carry
             # dynamic-slice the KV blocks out of the full tensors (closed
-            # over) instead of feeding stacked slices through scan xs — this
-            # avoids materialising staggered copies of K/V per query block.
+            # over) instead of feeding stacked slices through the loop —
+            # this avoids materialising staggered copies of K/V.
             start = bi * kv_block
             kb_ = jax.lax.dynamic_slice_in_dim(k, start, kv_block, axis=1)
             vb_ = jax.lax.dynamic_slice_in_dim(v, start, kv_block, axis=1)
             kp_ = start + jnp.arange(kv_block, dtype=jnp.int32)
             kb_r = _repeat_kv(kb_, H)
             vb_r = _repeat_kv(vb_, H)
-            s = jnp.einsum("bqhd,bkhd->bhqk", qb, kb_r,
-                           preferred_element_type=jnp.float32) * scale
-            if softcap_val:
-                s = softcap_val * jnp.tanh(s / softcap_val)
-            msk = jnp.ones((q_block, kv_block), bool)
-            if causal:
-                msk &= qpos[:, None] >= kp_[None, :]
-            if window:
-                msk &= (qpos[:, None] - kp_[None, :]) < window
-            s = jnp.where(msk[None, None], s, NEG_INF)
+            s, _ = _block_scores(qb, kb_r, qpos, kp_, **masks)
             m_new = jnp.maximum(m, s.max(axis=-1))
             p = jnp.exp(s - m_new[..., None])
             corr = jnp.exp(m - m_new)
@@ -135,16 +159,107 @@ def mha_chunked(q, k, v, *, causal=True, window=0, softcap_val=0.0,
             pv = jnp.einsum("bhqk,bkhd->bhqd", p.astype(vb_r.dtype), vb_r,
                             preferred_element_type=jnp.float32)
             acc_new = acc * corr[..., None] + pv
-            return (m_new, l_new, acc_new), None
+            return m_new, l_new, acc_new
 
-        # flash-attention semantics: scores/probs are *recomputed* in the
-        # backward pass (checkpoint), so per-step residuals are just the
-        # small (m,l,acc) carry — not the (qb,kb) probability matrices.
-        idxs = jnp.arange(lo // kv_block, hi // kv_block, dtype=jnp.int32)
-        (m, l, acc), _ = jax.lax.scan(jax.checkpoint(body), (m0, l0, a0), idxs)
-        ob = acc / jnp.maximum(l, 1e-30)[..., None]
-        out_blocks.append(ob.swapaxes(1, 2))            # (B,qb,H,hd)
-    return jnp.concatenate(out_blocks, axis=1).astype(q.dtype)
+        m0 = jnp.full((B, H, q_block), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((B, H, q_block), jnp.float32)
+        a0 = jnp.zeros((B, H, q_block, hd), jnp.float32)
+        m, l, acc = jax.lax.fori_loop(lo, hi, body, (m0, l0, a0))
+        l = jnp.maximum(l, 1e-30)
+        ob = (acc / l[..., None]).swapaxes(1, 2)        # (B,qb,H,hd)
+        return None, (ob.astype(q.dtype), m + jnp.log(l))
+
+    _, (obs, lses) = jax.lax.scan(qblock, None, blocks)
+    return obs.swapaxes(0, 1).reshape(B, S, H, hd), lses
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _mha_chunked(q, k, v, causal, window, softcap_val, q_block, kv_block,
+                 q_offset):
+    return _mha_forward(q, k, v, causal, window, softcap_val, q_block,
+                        kv_block, q_offset)[0]
+
+
+def _mha_chunked_fwd(q, k, v, causal, window, softcap_val, q_block,
+                     kv_block, q_offset):
+    out, lses = _mha_forward(q, k, v, causal, window, softcap_val, q_block,
+                             kv_block, q_offset)
+    return out, (q, k, v, out, lses)
+
+
+def _mha_chunked_bwd(causal, window, softcap_val, q_block, kv_block,
+                     q_offset, res, g):
+    """Flash attention's backward: per query block, a loop over its KV
+    blocks recomputes the probabilities from the saved log-sum-exp and
+    accumulates dq, and dk, dv in f32 over the whole sequence."""
+    q, k, v, out, lses = res
+    B, S, H, hd = q.shape
+    T, Kv = k.shape[1], k.shape[2]
+    rep = H // Kv
+    scale = 1.0 / math.sqrt(hd)
+    masks = dict(scale=scale, causal=causal, window=window,
+                 softcap_val=softcap_val)
+    blocks = _kv_blocks(S, T, causal=causal, window=window, q_block=q_block,
+                        kv_block=kv_block, q_offset=q_offset)
+    f32 = jnp.float32
+
+    def fold(x):                                        # heads -> kv heads
+        return x.reshape(B, kv_block, Kv, rep, hd).sum(3)
+
+    def qblock(carry, xs):
+        dq, dk, dv = carry
+        (qs, lo, hi), lse = (xs[0][0], xs[0][1], xs[0][2]), xs[1]
+        qb = jax.lax.dynamic_slice_in_dim(q, qs, q_block, axis=1)
+        gb = jax.lax.dynamic_slice_in_dim(g, qs, q_block, axis=1)
+        ob = jax.lax.dynamic_slice_in_dim(out, qs, q_block, axis=1)
+        qpos = jnp.arange(q_block, dtype=jnp.int32) + q_offset + qs
+        # rowsum(dO * O): the softmax's backward term, (B,H,qb)
+        delta = jnp.sum(gb.astype(f32) * ob.astype(f32),
+                        axis=-1).swapaxes(1, 2)
+
+        def body(bi, c):
+            dqb, dk, dv = c
+            start = bi * kv_block
+            kb_ = jax.lax.dynamic_slice_in_dim(k, start, kv_block, axis=1)
+            vb_ = jax.lax.dynamic_slice_in_dim(v, start, kv_block, axis=1)
+            kp_ = start + jnp.arange(kv_block, dtype=jnp.int32)
+            kb_r = _repeat_kv(kb_, H)
+            vb_r = _repeat_kv(vb_, H)
+            s, t = _block_scores(qb, kb_r, qpos, kp_, **masks)
+            p = jnp.exp(s - lse[..., None])
+            dp = jnp.einsum("bqhd,bkhd->bhqk", gb, vb_r,
+                            preferred_element_type=f32)
+            ds = p * (dp - delta[..., None])
+            if t is not None:
+                ds = ds * (1.0 - t * t)
+            ds = (ds * scale).astype(q.dtype)
+            dqb = dqb + jnp.einsum("bhqk,bkhd->bqhd", ds, kb_r,
+                                   preferred_element_type=f32)
+            dk_j = fold(jnp.einsum("bhqk,bqhd->bkhd", ds, qb,
+                                   preferred_element_type=f32))
+            dv_j = fold(jnp.einsum("bhqk,bqhd->bkhd", p.astype(g.dtype), gb,
+                                   preferred_element_type=f32))
+            dk = jax.lax.dynamic_update_slice_in_dim(
+                dk, jax.lax.dynamic_slice_in_dim(dk, start, kv_block, 1)
+                + dk_j, start, axis=1)
+            dv = jax.lax.dynamic_update_slice_in_dim(
+                dv, jax.lax.dynamic_slice_in_dim(dv, start, kv_block, 1)
+                + dv_j, start, axis=1)
+            return dqb, dk, dv
+
+        dq0 = jnp.zeros((B, q_block, H, hd), f32)
+        dqb, dk, dv = jax.lax.fori_loop(lo, hi, body, (dq0, dk, dv))
+        dq = jax.lax.dynamic_update_slice_in_dim(dq, dqb.astype(q.dtype), qs,
+                                                 axis=1)
+        return (dq, dk, dv), None
+
+    init = (jnp.zeros(q.shape, q.dtype), jnp.zeros((B, T, Kv, hd), f32),
+            jnp.zeros((B, T, Kv, hd), f32))
+    (dq, dk, dv), _ = jax.lax.scan(qblock, init, (blocks, lses))
+    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+_mha_chunked.defvjp(_mha_chunked_fwd, _mha_chunked_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -199,10 +314,12 @@ def decode_attention(q, cache, *, window=0, softcap_val=0.0, cur_pos=None):
 # ---------------------------------------------------------------------------
 
 def attn_apply(params, x, *, cfg, window: int = 0, rope_theta=None,
-               cache=None, cur_pos=None, impl: Optional[str] = None):
+               yarn=None, cache=None, cur_pos=None,
+               impl: Optional[str] = None):
     """x: (B,S,D). If ``cache`` is provided, runs one decode step and returns
     (out, new_cache); else runs train/prefill and returns (out, (k,v)).
-    ``window``: 0 = full attention (callers resolve gemma2 local/global)."""
+    ``window``: 0 = full attention (callers resolve a layer period's
+    windows); ``yarn``: the layer's YaRN scaling of RoPE, if any."""
     dt = x.dtype
     theta = rope_theta if rope_theta is not None else cfg.rope_theta
     B, S, D = x.shape
@@ -212,8 +329,8 @@ def attn_apply(params, x, *, cfg, window: int = 0, rope_theta=None,
 
     if cache is not None:
         pos = jnp.broadcast_to(cur_pos, (B, S))
-        q = apply_rope(q, pos, theta)
-        k = apply_rope(k, pos, theta)
+        q = apply_rope(q, pos, theta, yarn)
+        k = apply_rope(k, pos, theta, yarn)
         new_cache = cache_write(cache, k, v, cur_pos)
         out = decode_attention(q, new_cache, window=window,
                                softcap_val=cfg.attn_softcap, cur_pos=cur_pos)
@@ -221,8 +338,8 @@ def attn_apply(params, x, *, cfg, window: int = 0, rope_theta=None,
         return o, new_cache
 
     pos = jnp.broadcast_to(jnp.arange(S), (B, S))
-    q = apply_rope(q, pos, theta)
-    k = apply_rope(k, pos, theta)
+    q = apply_rope(q, pos, theta, yarn)
+    k = apply_rope(k, pos, theta, yarn)
     from repro.parallel import constrain_qkv
     q, k, v = constrain_qkv(q, k, v)
     impl = impl or cfg.attn_impl

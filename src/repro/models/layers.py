@@ -7,6 +7,8 @@ annotations (see ``repro.parallel.sharding``).
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -43,12 +45,39 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (..., S, H, hd); positions: broadcastable to (..., S)."""
+def yarn_freqs(head_dim: int, theta: float, factor: float, original_max: int,
+               beta_fast: float, beta_slow: float) -> jnp.ndarray:
+    """YaRN's inverse frequencies (Peng et al. 2023, as Hugging Face's
+    ``_compute_yarn_parameters``): the interpolated (``/ factor``) and the
+    extrapolated frequencies blended by a linear ramp over the dimensions
+    between the ``beta_fast`` and ``beta_slow`` rotation counts of the
+    original context."""
+    def dim_of(rotations):
+        return (head_dim * math.log(original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), head_dim - 1)
+    extra = rope_freqs(head_dim, theta)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    keep = 1.0 - ramp                      # share of the extrapolated frequency
+    return extra / factor * (1.0 - keep) + extra * keep
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               yarn=None) -> jnp.ndarray:
+    """x: (..., S, H, hd); positions: broadcastable to (..., S).  ``yarn``
+    (``ModelConfig.rope_yarn``) scales the frequencies and multiplies cos
+    and sin by its attention factor."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                        # (hd/2,)
+    if yarn is None:
+        freqs = rope_freqs(hd, theta)                    # (hd/2,)
+    else:
+        freqs = yarn_freqs(hd, theta, *yarn[:4])
     ang = positions[..., :, None, None].astype(jnp.float32) * freqs  # (...,S,1,hd/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        cos, sin = cos * yarn[4], sin * yarn[4]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -102,12 +131,13 @@ def embed(params, tokens, scale: bool = False):
     return e
 
 
-def chunked_ce_loss(emb_params, h, labels, *, chunk: int, final_softcap: float = 0.0,
+def chunked_ce_loss(head_params, h, labels, *, chunk: int, final_softcap: float = 0.0,
                     mask=None):
-    """Cross-entropy with the LM head applied in sequence chunks so the full
+    """Cross-entropy with the LM head (``{"embedding": (V, D)}``: the tied
+    embedding or an untied head) applied in sequence chunks so the full
     (B,S,V) logits tensor never materialises. h: (B,S,D), labels: (B,S)."""
     B, S, D = h.shape
-    table = emb_params["embedding"].astype(COMPUTE_DTYPE)     # (V, D)
+    table = head_params["embedding"].astype(COMPUTE_DTYPE)    # (V, D)
     chunk = min(chunk, S)
     n_chunks = S // chunk
     assert S % chunk == 0, (S, chunk)

@@ -2,15 +2,21 @@
 train / prefill / serve step functions.
 
 Layer stacks are ``lax.scan``s over vmapped-init (stacked) block params so the
-block body compiles once regardless of depth; gemma2's local/global
-alternation scans over *pairs* so each position keeps a static window (and the
+block body compiles once regardless of depth; a layer period (gemma2's
+local/global pairs, mellum2's three windowed layers and one full) scans over
+*periods* so each position keeps a static window and its RoPE (and the
 chunked attention keeps static KV-block skipping). zamba2 scans over groups of
 ``shared_attn_every`` mamba2 blocks followed by one application of a single
 shared attention block.
+
+``lm_sgd_train`` is a federated worker's local training of a language model
+(full-batch SGD on f32 master weights, bf16 compute), and ``lm_hits`` its
+evaluation.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -38,7 +44,8 @@ def _attn_block_init(rng, cfg):
                                    cfg.hd),
     }
     if cfg.is_moe:
-        p["moe"] = moe_mod.moe_init(k2, cfg.d_model, cfg.d_ff, cfg.n_experts)
+        p["moe"] = moe_mod.moe_init(k2, cfg.d_model, cfg.d_ff, cfg.n_experts,
+                                    cfg.n_experts_here)
     else:
         p["mlp"] = glu_mlp_init(k2, cfg.d_model, cfg.d_ff)
     if cfg.post_block_norm:
@@ -73,14 +80,18 @@ def init_params(rng, cfg):
     ke, kb, ks = jax.random.split(rng, 3)
     params = {"embed": embed_init(ke, cfg.vocab_size, cfg.d_model),
               "final_norm": rmsnorm_init(cfg.d_model)}
+    if not cfg.tie_embeddings:
+        params["lm_head"] = embed_init(jax.random.fold_in(ke, 1),
+                                       cfg.vocab_size, cfg.d_model)
     if cfg.block_type == "attn":
         init1 = lambda k: _attn_block_init(k, cfg)
-        if cfg.alt_local_global:
-            assert cfg.n_layers % 2 == 0
-            pair = lambda k: jax.tree.map(
+        P = len(cfg.period)
+        if P:
+            assert cfg.n_layers % P == 0
+            period = lambda k: jax.tree.map(
                 lambda *xs: jnp.stack(xs),
-                *[_attn_block_init(kk, cfg) for kk in jax.random.split(k, 2)])
-            params["blocks"] = _stack_init(kb, pair, cfg.n_layers // 2)
+                *[_attn_block_init(kk, cfg) for kk in jax.random.split(k, P)])
+            params["blocks"] = _stack_init(kb, period, cfg.n_layers // P)
         else:
             params["blocks"] = _stack_init(kb, init1, cfg.n_layers)
     elif cfg.block_type == "rwkv6":
@@ -113,14 +124,19 @@ def init_params(rng, cfg):
 
 def _attn_block_apply(p, x, cfg, *, window, cache=None, cur_pos=None):
     h = rmsnorm(p["ln1"], x)
+    # YaRN, where the config has it, is the full layers' RoPE
+    yarn = cfg.rope_yarn if window == 0 else None
     a, kv = attn_mod.attn_apply(p["attn"], h, cfg=cfg, window=window,
-                                cache=cache, cur_pos=cur_pos)
+                                yarn=yarn, cache=cache, cur_pos=cur_pos)
     if cfg.post_block_norm:
         a = rmsnorm(p["post_ln1"], a)
     x = x + a
     h = rmsnorm(p["ln2"], x)
     aux = jnp.zeros((), jnp.float32)
-    if cfg.is_moe:
+    if cfg.experts_held:
+        f, aux = moe_mod.moe_share_apply(p["moe"], h, top_k=cfg.top_k,
+                                         expert_offset=cfg.expert_offset)
+    elif cfg.is_moe:
         f, aux = moe_mod.moe_apply(p["moe"], h, top_k=cfg.top_k,
                                    capacity_factor=cfg.capacity_factor)
     else:
@@ -185,10 +201,11 @@ def init_decode_state(cfg, batch: int, context_len: int, dtype=COMPUTE_DTYPE):
         }
 
     if cfg.block_type == "attn":
-        if cfg.alt_local_global:
-            L = cfg.n_layers // 2
+        P = len(cfg.period)
+        if P:
+            L = cfg.n_layers // P
             return {"kv": jax.tree.map(
-                lambda z: z.reshape((L, 2) + z.shape[1:]), kv(cfg.n_layers))}
+                lambda z: z.reshape((L, P) + z.shape[1:]), kv(cfg.n_layers))}
         return {"kv": kv(cfg.n_layers)}
     if cfg.block_type == "rwkv6":
         L, D, H, K = cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.ssm_head_dim
@@ -241,30 +258,38 @@ def forward(params, cfg, *, tokens=None, embeds=None, state=None, cur_pos=None,
 
     if cfg.block_type == "attn":
         blocks = params["blocks"]
-        if cfg.alt_local_global:
-            def pair_body(carry, xs):
+        train = cfg.remat and not decode and not return_cache
+        if cfg.period:
+            def layer(window):
+                def apply(p, x, cache):
+                    return _attn_block_apply(p, x, cfg, window=window,
+                                             cache=cache, cur_pos=cur_pos)
+                # training: one layer's activations live at a time in backward
+                return jax.checkpoint(apply) if train else apply
+
+            def period_body(carry, xs):
                 x, aux = carry
                 x = constrain_act(x)
                 p, st = xs
-                p0 = jax.tree.map(lambda t: t[0], p)
-                p1 = jax.tree.map(lambda t: t[1], p)
-                c0 = jax.tree.map(lambda t: t[0], st["kv"]) if decode else None
-                c1 = jax.tree.map(lambda t: t[1], st["kv"]) if decode else None
-                x, a0, kv0 = _attn_block_apply(p0, x, cfg, window=cfg.window,
-                                               cache=c0, cur_pos=cur_pos)
-                x, a1, kv1 = _attn_block_apply(p1, x, cfg, window=0,
-                                               cache=c1, cur_pos=cur_pos)
+                kvs = []
+                for j, window in enumerate(cfg.period):
+                    pj = jax.tree.map(lambda t: t[j], p)
+                    cj = (jax.tree.map(lambda t: t[j], st["kv"]) if decode
+                          else None)
+                    x, a, kv = layer(window)(pj, x, cj)
+                    aux = aux + a
+                    kvs.append(kv)
+                stack = lambda *xs: jnp.stack(xs)
                 if decode:
-                    ys = {"kv": jax.tree.map(lambda a, b: jnp.stack([a, b]), kv0, kv1)}
+                    ys = {"kv": jax.tree.map(stack, *kvs)}
                 elif return_cache:
                     C = cache_len or cfg.kv_cache_len(S)
-                    ys = {"kv": jax.tree.map(lambda a, b: jnp.stack([a, b]),
-                                             _kv_from_full(*kv0, C),
-                                             _kv_from_full(*kv1, C))}
+                    ys = {"kv": jax.tree.map(
+                        stack, *[_kv_from_full(*kv, C) for kv in kvs])}
                 else:
                     ys = 0
-                return (x, aux + a0 + a1), ys
-            body = pair_body
+                return (x, aux), ys
+            body = period_body
         else:
             def blk_body(carry, xs):
                 x, aux = carry
@@ -282,8 +307,8 @@ def forward(params, cfg, *, tokens=None, embeds=None, state=None, cur_pos=None,
                     ys = 0
                 return (x, aux + a), ys
             body = blk_body
-        if cfg.remat and not decode and not return_cache:
-            body = jax.checkpoint(body)
+            if train:
+                body = jax.checkpoint(body)
         if decode:
             st_xs = state
         else:
@@ -379,8 +404,13 @@ def forward(params, cfg, *, tokens=None, embeds=None, state=None, cur_pos=None,
 # Steps
 # ---------------------------------------------------------------------------
 
+def head_params(params, cfg):
+    """The output head: the tied embedding, or the untied ``lm_head``."""
+    return params["embed"] if cfg.tie_embeddings else params["lm_head"]
+
+
 def logits_from_hidden(params, cfg, h):
-    table = params["embed"]["embedding"].astype(h.dtype)
+    table = head_params(params, cfg)["embedding"].astype(h.dtype)
     logits = h @ table.T
     if cfg.final_softcap:
         logits = softcap(logits, cfg.final_softcap)
@@ -390,7 +420,7 @@ def logits_from_hidden(params, cfg, h):
 def loss_fn(params, cfg, batch, aux_weight: float = 0.01):
     h, aux, _ = forward(params, cfg,
                         tokens=batch.get("tokens"), embeds=batch.get("embeds"))
-    loss = chunked_ce_loss(params["embed"], h, batch["labels"],
+    loss = chunked_ce_loss(head_params(params, cfg), h, batch["labels"],
                            chunk=cfg.loss_chunk,
                            final_softcap=cfg.final_softcap,
                            mask=batch.get("mask"))
@@ -459,3 +489,51 @@ def serve_step(params, state, tokens, cur_pos, *, cfg, embeds=None):
                               state=state, cur_pos=cur_pos)
     logits = logits_from_hidden(params, cfg, h)
     return logits, new_state
+
+
+# ---------------------------------------------------------------------------
+# A federated worker's local training and evaluation
+# ---------------------------------------------------------------------------
+
+@functools.partial(jax.jit, static_argnames=("epochs", "lr", "cfg"))
+def lm_sgd_train(params, tokens, labels, epochs: int, lr: float, cfg):
+    """``epochs`` full-batch SGD steps of next-token cross-entropy over a
+    worker's shard (``tokens``, ``labels``: (n, S) ids).  The weights stay
+    f32 and are cast to bf16 for compute.  Each step's gradient is the mean
+    over all n x S tokens at the step's weights, taken ``cfg.microbatches``
+    groups of sequences at a time so that one group's activations are
+    live, and each group's part is subtracted from the f32 weights as it
+    comes (no separate gradient buffer)."""
+    n = tokens.shape[0]
+    groups = math.gcd(n, max(cfg.microbatches, 1))
+    tok = tokens.reshape(groups, n // groups, -1)
+    lab = labels.reshape(groups, n // groups, -1)
+
+    def group_loss(pc, t, y):
+        h, _, _ = forward(pc, cfg, tokens=t)
+        return chunked_ce_loss(head_params(pc, cfg), h, y,
+                               chunk=cfg.loss_chunk) / groups
+
+    def step(p, _):
+        # the bf16 copy's gradient is the f32 weights' gradient (a cast's
+        # transpose); every group's is taken at the step's weights
+        pc = jax.tree.map(lambda a: a.astype(COMPUTE_DTYPE), p)
+
+        def descend(q, xs):
+            g = jax.grad(group_loss)(pc, *xs)
+            return jax.tree.map(lambda a, b: a - lr * b.astype(a.dtype),
+                                q, g), None
+        p, _ = jax.lax.scan(descend, p, (tok, lab))
+        return p, None
+
+    params, _ = jax.lax.scan(step, params, None, length=epochs)
+    return params
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def lm_hits(params, tokens, labels, cfg):
+    """Next-token top-1 hits over (n, S) ids, at bf16 compute."""
+    pc = jax.tree.map(lambda a: a.astype(COMPUTE_DTYPE), params)
+    h, _, _ = forward(pc, cfg, tokens=tokens)
+    logits = logits_from_hidden(pc, cfg, h)
+    return jnp.sum(jnp.argmax(logits, axis=-1) == labels)

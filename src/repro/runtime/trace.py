@@ -11,15 +11,17 @@ context and costs about half a microsecond.
 always on; ``counters()`` returns a copy.  Read a stretch of a run as the
 difference of two snapshots.  The tallies:
 
-* ``host_syncs``: blocking device-to-host reads on the federation's path
-  (each ``fl.sync`` site, and each evaluation);
+* ``host_syncs``: blocking device-to-host reads and waits on the
+  federation's path (each ``fl.sync`` site, and each evaluation);
 * ``h2d_bytes``: bytes of host arrays copied to the device on that path
   (training data, merge weights, server-optimizer scalars).  A worker
   keeps its shard on the device, so its bytes count once, at its first
   training;
 * ``shard_uploads``: trainings that copied the worker's shard to the
   device (its first);
-* ``shard_hits``: trainings served from the shard already there.
+* ``shard_hits``: trainings served from the shard already there;
+* ``train_tokens``: tokens a language model's local trainings processed
+  (the shard's tokens times its epochs, read from the shape).
 """
 from __future__ import annotations
 

@@ -221,8 +221,9 @@ def test_per_device_row_buffer_shrinks_linearly(d):
     total = 4 * st.bundle.padded_size * 4            # (W, N) f32 bytes
     per_dev = st.row_bytes_by_device()
     assert len(per_dev) == d and set(per_dev.values()) == {total // d}
-    # ... and the packed server mirror shards the same way
-    srv = {s.data.nbytes for s in st._server_flat.addressable_shards}
+    # ... and the packed server model a mixing merge reads shards the
+    # same way
+    srv = {s.data.nbytes for s in st._server_buffer(t).addressable_shards}
     assert srv == {st.bundle.padded_size * 4 // d}
 
 

@@ -77,6 +77,7 @@ def test_full_config_shapes(arch):
         "zamba2-7b": (81, 3584, 32, 32, 14336, 32000),
         "internvl2-26b": (48, 6144, 48, 8, 16384, 92553),
         "musicgen-medium": (48, 1536, 24, 24, 6144, 2048),
+        "mellum2-12b-a2.5b": (28, 2304, 32, 4, 896, 98304),
     }[arch]
     assert (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
             cfg.d_ff, cfg.vocab_size) == spec

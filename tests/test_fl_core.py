@@ -222,3 +222,26 @@ def test_pointer_identity():
     p1 = Pointer("worker://w0", "obj1")
     p2 = Pointer("worker://w0", "obj1")
     assert p1 == p2 and str(p1) == "worker://w0/obj1"
+
+
+@pytest.mark.parametrize("mode", ["sync", "async"])
+def test_a_trained_model_is_freed_without_the_cycle_collector(mode):
+    """A finished training leaves no reference cycle that holds its input
+    model: with the cyclic collector off, the models alive after many
+    rounds are the setup's, the server's and the few in flight."""
+    import gc
+    from repro.core.experiment import build_experiment, make_setup
+    setup = make_setup([1] * 6, n_test=64, seed=3)
+    loop, server = build_experiment(setup, mode=mode, epochs_per_round=1,
+                                    max_rounds=12)
+    shape = jax.tree.leaves(setup.weights0)[0].shape
+    gc.collect()
+    gc.disable()
+    try:
+        server.start()
+        loop.run(max_events=100_000)
+        alive = sum(1 for a in jax.live_arrays() if a.shape == shape)
+    finally:
+        gc.enable()
+    assert server.version >= 10
+    assert alive <= 8, alive
