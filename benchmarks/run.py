@@ -14,7 +14,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 def main() -> None:
     from benchmarks import agg_shard_bench, fl_figures, roofline, \
-        scale_bench, wire_bench
+        scale_bench
     from repro.runtime.compile_cache import enable_compile_cache
 
     enable_compile_cache()
@@ -48,7 +48,7 @@ def main() -> None:
         return
 
     # a benchmark that raises fails the whole run, naming itself
-    for bench in (agg_shard_bench.main, wire_bench.main, scale_bench.main):
+    for bench in (agg_shard_bench.main, scale_bench.main):
         try:
             bench()
         except Exception as e:

@@ -28,7 +28,6 @@ import argparse
 import contextlib
 import functools
 import json
-import os
 import sys
 import time
 from pathlib import Path
@@ -95,10 +94,6 @@ def device_gate(chips: int) -> dict:
           f"device_kind {d0.device_kind!r}, {len(devs)} device(s)")
     if d0.platform != "tpu":
         sys.exit(f"chip_smoke: needs a TPU, JAX found {d0.platform!r}")
-    for var in ("REPRO_FLAT_PALLAS", "REPRO_AGG_PATH"):
-        if var in os.environ:
-            sys.exit(f"chip_smoke: {var} is set; unset it to run the "
-                     "default path")
     flags = kernels.pallas_flags(None, None)
     if flags != (True, False):
         sys.exit(f"chip_smoke: kernels resolve to (use_pallas, interpret)"

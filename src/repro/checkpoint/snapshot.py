@@ -328,16 +328,12 @@ def _restore_population(pop, img: Optional[dict]) -> None:
         getattr(pop, name)[:n] = arr
 
 
-def _capture_flat(fl) -> Optional[dict]:
-    if fl is None:
-        return None
+def _capture_flat(fl) -> dict:
     return {"rows": fl._rows, "free": list(fl._free),
             "next_row": fl._next_row, "dirty": set(fl._dirty)}
 
 
-def _restore_flat(fl, img: Optional[dict]) -> None:
-    if img is None or fl is None:
-        return
+def _restore_flat(fl, img: dict) -> None:
     fl._rows = img["rows"]
     fl._free = list(img["free"])
     fl._next_row = img["next_row"]

@@ -4,9 +4,8 @@ worker selection (Algorithms 1 & 2), eq-3.4 time estimation, deterministic
 event-driven sync/async runtime, pod-level federated training, the
 wire-aware transport layer (codec'd flat-buffer weight exchange with exact
 byte accounting), hierarchical multi-server topologies (leaf servers over
-disjoint worker pools re-aggregated at a root), and beyond-paper update
-compression."""
-from . import (aggregation, compression, estimator, events, federated,
+disjoint worker pools re-aggregated at a root)."""
+from . import (aggregation, estimator, events, federated,
                flatbuf, population, selection, server, server_opt, topology,
                transport, warehouse, worker)
 from .experiment import (TABLE_4_1, TABLE_4_2, make_setup, repartition_setup,

@@ -1,71 +1,29 @@
-"""Aggregation algorithms (thesis §2.1.3, eqs 2.1–2.7).
+"""Aggregation weights (thesis §2.1.3, eqs 2.1–2.7).
 
-All operate on model-weight pytrees. ``staleness`` of a response is
-``i - xi``: current server version minus the server version the worker
-fetched before training.
+``staleness`` of a response is ``i - xi``: current server version minus
+the server version the worker fetched before training.
 
-The pytree API is a thin wrapper over the flat-buffer fast path
-(``core.flatbuf``): updates are packed once into a contiguous ``(W, N)``
-buffer and merged in a single fused pass instead of a per-leaf, per-worker
-tree-map.  ``_weighted_mean`` is the per-leaf reference implementation
-(kept as the parity oracle; set ``REPRO_AGG_PATH=tree`` to force it).
+Every named aggregator is a scalar weight per update: the merge itself is
+one fused pass over the packed ``(W, N)`` row buffer
+(``flatbuf.FlatServerState``).  The per-leaf arithmetic survives only as
+test oracles in ``kernels.ref``.
 """
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass
-from typing import Callable, List, Sequence
+from typing import List
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-
-from . import flatbuf
 
 
 @dataclass(frozen=True)
 class WorkerUpdate:
-    weights: object          # pytree
+    weights: object          # packed row vector, or a claimed window row
     staleness: int = 0       # i - xi
     n_data: int = 1          # batches of training data the worker used
 
 
-def _weighted_mean(trees: Sequence, weights: Sequence[float]):
-    """Per-leaf reference path: W reads + W-1 adds per leaf."""
-    w = flatbuf.normalized_weights(weights)
-
-    def agg(*leaves):
-        out = jnp.zeros_like(leaves[0], dtype=jnp.float32)
-        for wi, leaf in zip(w, leaves):
-            out = out + wi * leaf.astype(jnp.float32)
-        return out.astype(leaves[0].dtype)
-    return jax.tree.map(agg, *trees)
-
-
-def _weighted_mean_flat(trees: Sequence, weights: Sequence[float]):
-    """Flat fast path: pack once, one fused contraction, unpack."""
-    w = flatbuf.normalized_weights(weights)
-    bundle = flatbuf.bundle_for(trees[0])
-    rows = bundle.pack_many(trees)
-    merged = flatbuf.fused_weighted_sum(rows, w)
-    return bundle.unpack(merged)
-
-
-def weighted_mean(trees: Sequence, weights: Sequence[float]):
-    if (os.environ.get("REPRO_AGG_PATH") != "tree"
-            and flatbuf.packable(trees[0])):
-        return _weighted_mean_flat(trees, weights)
-    return _weighted_mean(trees, weights)
-
-
-# --- eq 2.1 / 2.2: federated averaging (sync + async are the same formula;
-# async simply admits updates with staleness > 0) -------------------------
-
-def fedavg(updates: List[WorkerUpdate]):
-    return weighted_mean([u.weights for u in updates], [1.0] * len(updates))
-
-
-# --- eqs 2.3-2.7: weighted federated averaging ----------------------------
+# --- eqs 2.5-2.7: staleness weight functions -----------------------------
 
 def linear_weight(staleness: int) -> float:          # eq 2.5
     return 1.0 / (staleness + 1.0)
@@ -79,27 +37,11 @@ def exponential_weight(staleness: int, a: float = 0.5) -> float:  # eq 2.7
     return float(np.exp(-a * staleness))
 
 
-def weighted_fedavg(updates: List[WorkerUpdate],
-                    weight_fn: Callable[[int], float] = linear_weight,
-                    data_weighted: bool = True):
-    """Eqs 2.3/2.4 with WEI_x from a staleness weight function, optionally
-    multiplied by each worker's data size (thesis §2.1.3: 'size of each
-    worker's available data' as an extra factor)."""
-    ws = [weight_fn(u.staleness) * (u.n_data if data_weighted else 1.0)
-          for u in updates]
-    return weighted_mean([u.weights for u in updates], ws)
-
-
-AGGREGATORS = {
-    "fedavg": fedavg,
-    "linear": lambda ups: weighted_fedavg(ups, linear_weight),
-    "polynomial": lambda ups: weighted_fedavg(ups, polynomial_weight),
-    "exponential": lambda ups: weighted_fedavg(ups, exponential_weight),
-}
-
-# per-update scalar weights of each named aggregator — lets the server fuse
-# the weighted sum and the alpha-mix into ONE kernel pass over the packed
-# buffers instead of AGGREGATORS[...] followed by mix_into
+# The aggregator registry: each name's per-update scalar weight.  "fedavg"
+# is eq 2.1/2.2 (sync and async are the same formula; async simply admits
+# updates with staleness > 0); the others are eqs 2.3/2.4 with WEI_x from
+# a staleness weight function times the worker's data size (thesis
+# §2.1.3: 'size of each worker's available data' as an extra factor).
 UPDATE_WEIGHT_FNS = {
     "fedavg": lambda u: 1.0,
     "linear": lambda u: linear_weight(u.staleness) * u.n_data,
@@ -108,34 +50,14 @@ UPDATE_WEIGHT_FNS = {
 }
 
 
-def use_flat_vec(flat, transport, aggregator: str) -> bool:
-    """True when decoded payloads can land straight in the flat (W, N)
-    row buffer: the merge fast path is active, the transport resolves to
-    the SAME (mesh-aware) bundle (else decoded vectors would not match
-    the row buffer's padded width), and the aggregator has a scalar-
-    weight form.  Shared by the single-server and topology-root merge
-    paths — the invariants must never desynchronize between tiers."""
-    return (flat is not None and transport.flat_capable
-            and transport.bundle is flat.bundle
-            and aggregator in UPDATE_WEIGHT_FNS)
+def check_aggregator(name: str, role: str = "aggregator") -> None:
+    """Reject an aggregator name outside :data:`UPDATE_WEIGHT_FNS`."""
+    if name not in UPDATE_WEIGHT_FNS:
+        raise ValueError(f"unknown {role} {name!r}; "
+                         f"have {sorted(UPDATE_WEIGHT_FNS)}")
 
 
 def update_weights(aggregator: str, updates: List[WorkerUpdate]):
-    """Scalar merge weight per update, or None if ``aggregator`` has no
-    scalar-weight form (then the caller must use AGGREGATORS)."""
-    fn = UPDATE_WEIGHT_FNS.get(aggregator)
-    if fn is None:
-        return None
+    """Scalar merge weight per update under ``aggregator``."""
+    fn = UPDATE_WEIGHT_FNS[aggregator]
     return [fn(u) for u in updates]
-
-
-def mix_into(server_weights, aggregate, alpha: float = 1.0):
-    """Server-side mixing: M_{i+1} = (1-alpha)*M_i + alpha*aggregate.
-    alpha=1 reproduces the thesis' replace-on-aggregate; alpha<1 is the
-    standard async-FL damping for stale single-worker merges."""
-    if alpha >= 1.0:
-        return aggregate
-    return jax.tree.map(
-        lambda s, a: ((1 - alpha) * s.astype(jnp.float32)
-                      + alpha * a.astype(jnp.float32)).astype(s.dtype),
-        server_weights, aggregate)

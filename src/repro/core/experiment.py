@@ -260,7 +260,7 @@ def run_fl(setup: FLSetup, *, mode: str = "sync", selector: str = "all",
     FedAvg (in passthrough ``1x1`` the lone leaf carries it, preserving
     the passthrough bit-identity).  Degenerate settings (FedAvgM
     ``momentum=0, lr=1``; FedAdam ``beta1=beta2=0, tau=inf``; FedDyn
-    ``gamma=0``) short-circuit to plain ``mix_into`` bit-exactly.
+    ``gamma=0``) short-circuit to the plain merge bit-exactly.
 
     ``partition`` re-partitions the setup's pooled samples across workers
     without rebuilding the setup: ``"dirichlet"`` Dirichlet label skew

@@ -122,7 +122,8 @@ def fl_round(stacked_params, weights):
 def fl_round_delta_compressed(stacked_params, anchor_params, weights, *,
                               compressor):
     """Beyond-paper variant: aggregate *compressed deltas* from the anchor
-    (last merged model) instead of raw weights — see core/compression.py.
+    (last merged model) instead of raw weights — the top-k/int8 codecs of
+    core/transport.py (per-tensor oracles in kernels/ref.py).
 
     Deltas are compressed on the packed (n_pods, N) buffer, so top-k style
     compressors rank the whole model's coordinates globally (FedLab-style

@@ -1,4 +1,4 @@
-"""Flat-buffer aggregation fast path.
+"""Flat-buffer aggregation: the federation's one merge path.
 
 The server's merge hot loop used to walk the model pytree once per worker
 per leaf (W reads + W-1 adds per leaf, re-dispatched eagerly every round).
@@ -41,7 +41,6 @@ from __future__ import annotations
 
 import functools
 import heapq
-import os
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
@@ -75,13 +74,6 @@ def shard_spans(lo: int, hi: int, shard_size: int) -> Tuple[tuple, ...]:
         spans.append((d, lo - d * shard_size, end - d * shard_size, lo))
         lo, d = end, d + 1
     return tuple(spans)
-
-
-def packable(tree) -> bool:
-    """True if every leaf is a fixed-shape array (packs into one buffer)."""
-    leaves = jax.tree.leaves(tree)
-    return bool(leaves) and all(hasattr(l, "shape") and hasattr(l, "dtype")
-                                for l in leaves)
 
 
 class ParamBundle:
@@ -205,8 +197,18 @@ _BUNDLES: Dict[tuple, ParamBundle] = {}
 def bundle_for(template, mesh=None) -> ParamBundle:
     """Memoised ParamBundle keyed on (structure, shapes, dtypes, mesh) —
     the server and its transport resolve to the SAME sharded bundle, so
-    decoded payload vectors land in the row buffer shape-exactly."""
+    decoded payload vectors land in the row buffer shape-exactly.
+
+    The one packability rule of the federation: a weight tree whose leaves
+    are not all arrays is rejected here, naming the first such leaf."""
     leaves, treedef = jax.tree.flatten(template)
+    for i, l in enumerate(leaves):
+        if not (hasattr(l, "shape") and hasattr(l, "dtype")):
+            path, _ = jax.tree_util.tree_leaves_with_path(template)[i]
+            raise ValueError(
+                f"weight leaf {jax.tree_util.keystr(path)} is a "
+                f"{type(l).__name__}, not an array: the model does not pack "
+                "into one flat buffer")
     key = (treedef, tuple((tuple(l.shape), str(jnp.asarray(l).dtype))
                           for l in leaves), mesh)
     b = _BUNDLES.get(key)
@@ -311,8 +313,8 @@ def fused_merge(server_flat, rows, wvec, use_pallas: Optional[bool] = None,
 def fused_weighted_sum(rows, w, use_pallas: Optional[bool] = None,
                        interpret: Optional[bool] = None, mesh=None):
     """One-pass ``w @ rows`` (no server term — the alpha>=1 replace-on-
-    aggregate case must not read the server buffer at all: the reference
-    ``mix_into`` short-circuits there, and ``0 * server`` would turn a
+    aggregate case must not read the server buffer at all: the oracle
+    ``ref.reference_mix_into`` short-circuits there, and ``0 * server`` would turn a
     non-finite server model into NaN instead of replacing it)."""
     use_pallas, interpret = pallas_flags(use_pallas, interpret)
     wv = trace.to_device(w, jnp.float32)
@@ -328,18 +330,6 @@ def normalized_weights(weights: Sequence[float]) -> np.ndarray:
     if s <= 0:
         raise ValueError("aggregation weights sum to zero")
     return (w / s).astype(np.float32)
-
-
-def flat_state_for(weights, mesh=None) -> Optional["FlatServerState"]:
-    """The flat-buffer merge fast path for an aggregator over ``weights``,
-    or None when it doesn't apply (non-array weight trees, or
-    ``REPRO_AGG_PATH=tree`` forcing the per-leaf reference end to end).
-    One predicate shared by every merge owner — the single-server
-    ``AggregationServer`` and the topology root — so the fallback rules
-    can never drift apart between tiers."""
-    if packable(weights) and os.environ.get("REPRO_AGG_PATH") != "tree":
-        return FlatServerState(weights, mesh=mesh)
-    return None
 
 
 class FlatServerState:
@@ -454,7 +444,7 @@ class FlatServerState:
                          weights: Sequence[float], alpha: float):
         w = normalized_weights(weights)
         if alpha >= 1.0:
-            # replace-on-aggregate: no server term (matches mix_into's
+            # replace-on-aggregate: no server term (matches the oracle's
             # short-circuit; also skips the server read entirely)
             wv = np.zeros((self.capacity,), np.float32)
             wv[:n] = w
@@ -570,8 +560,8 @@ class FlatServerState:
 
     def delta_vec(self, cur_tree, new_vec, base_vec) -> jnp.ndarray:
         """``cur + (new - base)`` where new/base are already-packed flat
-        vectors; returns the packed result (async_delta on the transport
-        fast path keeps everything in flat-vector space).
+        vectors; returns the packed result (async_delta keeps everything
+        in flat-vector space).
 
         Reuses the packed server mirror when ``cur_tree`` is the tree the
         last merge produced — no fresh O(N) pack per response. The mirror

@@ -16,7 +16,6 @@ import random
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
-import jax
 import numpy as np
 
 from repro.runtime import trace
@@ -63,6 +62,7 @@ class AggregationServer:
                  max_resident_links: Optional[int] = None,
                  server_opt=None, server_opt_kw: Optional[dict] = None):
         assert mode in ("sync", "async")
+        agg.check_aggregator(aggregator)
         self.name = name
         self.address = f"server://{name}"
         self.weights = weights
@@ -98,18 +98,15 @@ class AggregationServer:
         self.async_latest_table = async_latest_table
         self._dispatch_base: Dict[str, object] = {}
         self._latest: Dict[str, tuple] = {}   # async: worker -> latest response
-        # flat-buffer merge fast path: packed server mirror + persistent
-        # (W, N) update rows; falls back to the pytree AGGREGATORS wrapper
-        # for non-array weight trees, unknown aggregator names, or when
-        # REPRO_AGG_PATH=tree forces the per-leaf reference end to end
-        self._flat = flatbuf.flat_state_for(weights, mesh=mesh)
-        # optional server-side optimizer (core/server_opt.py): with the
-        # flat substrate it rides the merge tails as one fused pass; on
-        # the tree fallback _aggregate applies step_tree per leaf
+        # the flat-buffer merge: persistent (W, N) update rows that decoded
+        # payloads land in, one fused pass per merge (rejects weight trees
+        # that do not pack)
+        self._flat = flatbuf.FlatServerState(weights, mesh=mesh)
+        # optional server-side optimizer (core/server_opt.py): rides the
+        # merge tails as one fused pass
         self.server_opt = server_opt_mod.make_server_opt(
             server_opt, **(server_opt_kw or {}))
-        if self._flat is not None:
-            self._flat.server_opt = self.server_opt
+        self._flat.server_opt = self.server_opt
         # single weight-exchange path: every transfer is a codec'd Payload
         # with exact wire bytes (core/transport.py); transport_down names
         # the downlink codec (None = symmetric with the uplink)
@@ -118,14 +115,13 @@ class AggregationServer:
                                                 down_codec=transport_down,
                                                 raw_bytes=model_bytes,
                                                 mesh=mesh)
+        if transport.bundle is not self._flat.bundle:
+            # decoded vectors must match the row buffer's padded width
+            raise ValueError("transport and server resolve different "
+                             "bundles (weight structure or mesh differ)")
         self.transport = transport
         self.total_up_bytes = 0
         self.total_down_bytes = 0
-        # decode straight into packed flat rows when the merge fast path
-        # is active AND the aggregator has a scalar-weight form (otherwise
-        # the pytree AGGREGATORS fallback needs trees in the cache)
-        self._use_vec = agg.use_flat_vec(self._flat, self.transport,
-                                         aggregator)
         # --- massive-scale control plane (core/population.py) ---
         # population: vectorized per-worker lanes — selection prices eq 3.4
         # population-wide in one fused pass instead of a per-object scan.
@@ -143,7 +139,7 @@ class AggregationServer:
         self.max_resident_links = max_resident_links
         self._profiles_view = None          # cached population view
         self._row_of: Dict[str, int] = {}   # worker -> claimed window row
-        self._window = cohort is not None and self._use_vec
+        self._window = cohort is not None
         if self._window:
             self._flat.window_rows = cohort
         self._inflight_w: set = set()       # dispatched, response pending
@@ -363,14 +359,10 @@ class AggregationServer:
         down = link.encode_down(self.weights)
         self.total_down_bytes += down.wire_bytes
         if self.async_delta:
-            base = self.weights
-            if not self._use_vec and self.transport.spec_down.delta:
-                # compressed downlink: the worker starts from the (lossy)
-                # reconstruction, not the exact server model — the delta-
-                # accumulate base must match it (the fast path reads the
-                # packed link.tx_base directly)
-                base = self.transport.bundle.unpack(link.tx_base)
-            self._dispatch_base[wid] = base
+            # a compressed downlink's worker starts from the (lossy)
+            # reconstruction: the delta-accumulate then reads the packed
+            # link.tx_base instead of this base
+            self._dispatch_base[wid] = self.weights
         self._inflight_w.add(wid)
         w.train_async(self.pointer, down, base_version,
                       self.epochs_per_round, link, self._on_response)
@@ -406,33 +398,18 @@ class AggregationServer:
                 return
             link = self.transport.link(res.worker_id)
             with trace.span("fl.decode_up", worker=res.worker_id):
-                if self._use_vec:
-                    # fast path: decode straight to a packed flat vector
-                    # (for compressed codecs: base + dequantised delta in
-                    # one fused pass); it lands in the (W, N) row buffer at
-                    # merge time
-                    weights = link.decode_up_vec(payload)
-                else:
-                    weights = link.decode_up_tree(payload)
+                # decode straight to a packed flat vector (for compressed
+                # codecs: base + dequantised delta in one fused pass); it
+                # lands in the (W, N) row buffer at merge time
+                weights = link.decode_up_vec(payload)
             if self.async_delta and self.mode == "async":
+                # delta-accumulate in flat-vector space: cur + (new - base);
+                # delta codecs already hold the packed base on the link
                 base = self._dispatch_base.get(res.worker_id, self.weights)
-                if self._use_vec:
-                    # delta-accumulate in flat-vector space: cur + (new -
-                    # base); delta codecs already hold the packed base on
-                    # the link
-                    base_vec = (link.tx_base if self.transport.tracks_tx_base
-                                else self._flat.bundle.pack(base))
-                    weights = self._flat.delta_vec(self.weights, weights,
-                                                   base_vec)
-                elif self._flat is not None:
-                    # delta-accumulate on packed buffers: cur + (new - base)
-                    # in one fused pass instead of a per-leaf tree-map
-                    weights = self._flat.apply_delta(self.weights, weights,
-                                                     base)
-                else:
-                    weights = jax.tree.map(
-                        lambda cur, new, b: cur + (new - b), self.weights,
-                        weights, base)
+                base_vec = (link.tx_base if self.transport.tracks_tx_base
+                            else self._flat.bundle.pack(base))
+                weights = self._flat.delta_vec(self.weights, weights,
+                                               base_vec)
             if self._window:
                 # streaming cohort-windowed merge: the decoded vector lands in
                 # a claimed window row NOW, and from here on this update is
@@ -545,7 +522,7 @@ class AggregationServer:
             else:
                 alpha = 1.0
             ws = agg.update_weights(self.aggregator, self._cache)
-            if self._window and ws is not None:
+            if self._window:
                 # cohort window: cache entries carry claimed row indices; the
                 # merge contracts the O(cohort x N) window with each weight
                 # scattered to its row (same fused kernel as merge_rows)
@@ -558,25 +535,12 @@ class AggregationServer:
                     for row in self._row_of.values():
                         self._flat.win_release(row)
                     self._row_of.clear()
-            elif self._use_vec and ws is not None:
-                # fast path: responses were decoded straight to packed flat
-                # vectors; land them in the (W, N) row buffer and fuse the
+            else:
+                # responses were decoded straight to packed flat vectors;
+                # land them in the (W, N) row buffer and fuse the
                 # staleness-weighted sum + alpha-mix in one pass
                 self.weights = self._flat.merge_rows(
                     self.weights, [u.weights for u in self._cache], ws, alpha)
-            elif self._flat is not None and ws is not None:
-                # cache holds pytrees (non-flat transport): pack-and-merge
-                self.weights = self._flat.merge(
-                    self.weights, [u.weights for u in self._cache], ws, alpha)
-            else:
-                merged = agg.AGGREGATORS[self.aggregator](self._cache)
-                mixed = agg.mix_into(self.weights, merged, alpha)
-                if self.server_opt is not None:
-                    # tree fallback (REPRO_AGG_PATH=tree / non-packable
-                    # weights): the per-leaf reference optimizer path — the
-                    # flat substrate applies the fused pass in _finish instead
-                    mixed = self.server_opt.step_tree(self.weights, mixed)
-                self.weights = mixed
             # the pointer names the *model*: overwrite in place, uid stays
             # stable (workers' ACLs hold this pointer — thesis §3.3.1 step 7)
             self.warehouse.put(self.weights, uid=self.pointer.uid)

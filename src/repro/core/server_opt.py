@@ -39,10 +39,9 @@ non-IID splits induce (the full FedDyn adds a client-side dynamic
 regularizer, which in this harness is the worker-side FedProx term —
 ``models.mlp.mlp_prox_train`` / ``make_setup(fedprox_mu=)``).
 
-Reference paths: ``step_tree`` runs the same recursions per leaf with
-``jax.tree.map`` (state as a pytree) — it serves the
-``REPRO_AGG_PATH=tree`` end-to-end fallback and is the parity oracle for
-the fused pass (tests/test_server_opt.py, mesh in {1, 2, 4}).
+Reference path: ``kernels.ref.reference_server_opt`` runs the same
+recursions in plain jnp; mapped per leaf it is the parity oracle for the
+fused pass (tests/test_server_opt.py, mesh in {1, 2, 4}).
 """
 from __future__ import annotations
 
@@ -116,9 +115,6 @@ class ServerOpt:
         self._v = None              # adam second moment (N,)
         self._prev_vec = None       # packed server model pre-merge
         self._prev_tree = None      # identity key for _prev_vec
-        # tree-path state (REPRO_AGG_PATH=tree / non-packable models)
-        self._m_tree = None
-        self._v_tree = None
 
     # --- subclass hooks ---
     def _scalars(self) -> np.ndarray:
@@ -160,48 +156,6 @@ class ServerOpt:
         self._prev_vec = merged_vec
         self._prev_tree = out_tree
 
-    # --- per-leaf tree path (REPRO_AGG_PATH=tree / non-packable) ---
-    def step_tree(self, prev_tree, merged_tree):
-        """Same recursions per leaf — the parity oracle for the fused
-        pass, and the end-to-end path when the flat substrate is off."""
-        if self._degenerate():
-            return merged_tree
-        sc = [float(s) for s in self._scalars()]
-        zeros = lambda t: jax.tree.map(
-            lambda l: jnp.zeros(l.shape, jnp.float32), t)
-        if self._m_tree is None:
-            self._m_tree = zeros(prev_tree)
-        if self.adam and self._v_tree is None:
-            self._v_tree = zeros(prev_tree)
-        f32 = jnp.float32
-        if self.adam:
-            b1, b2, lr, tau = sc[:4]
-            self._m_tree = jax.tree.map(
-                lambda m, mg, p: b1 * m + (1.0 - b1)
-                * (mg.astype(f32) - p.astype(f32)),
-                self._m_tree, merged_tree, prev_tree)
-            self._v_tree = jax.tree.map(
-                lambda v, mg, p: b2 * v + (1.0 - b2)
-                * (mg.astype(f32) - p.astype(f32)) ** 2,
-                self._v_tree, merged_tree, prev_tree)
-            out = jax.tree.map(
-                lambda p, m, v: (p.astype(f32)
-                                 + lr * m / (jnp.sqrt(v) + tau)
-                                 ).astype(p.dtype),
-                prev_tree, self._m_tree, self._v_tree)
-        else:
-            am, bm, cd, lr = sc[:4]
-            self._m_tree = jax.tree.map(
-                lambda m, mg, p: am * m + bm * (mg.astype(f32)
-                                                - p.astype(f32)),
-                self._m_tree, merged_tree, prev_tree)
-            out = jax.tree.map(
-                lambda p, mg, m: (p.astype(f32)
-                                  + cd * (mg.astype(f32) - p.astype(f32))
-                                  + lr * m).astype(p.dtype),
-                prev_tree, merged_tree, self._m_tree)
-        return out
-
     # --- lifecycle ---
     def rebase(self) -> None:
         """The server model was replaced under us (root failover promoted
@@ -216,14 +170,11 @@ class ServerOpt:
         anchor is re-derived on restore (bitwise-same repack of the
         restored server model, mirroring ``_restore_flat``)."""
         return {"name": self.name, "kw": self._kwargs(),
-                "m": self._m, "v": self._v,
-                "m_tree": self._m_tree, "v_tree": self._v_tree}
+                "m": self._m, "v": self._v}
 
     def restore(self, img: dict) -> None:
         self._m = img["m"]
         self._v = img["v"]
-        self._m_tree = img["m_tree"]
-        self._v_tree = img["v_tree"]
         self.rebase()
 
     def _kwargs(self) -> dict:

@@ -72,8 +72,6 @@ from __future__ import annotations
 from dataclasses import dataclass, replace as dc_replace
 from typing import Callable, Dict, List, Optional, Sequence
 
-import jax
-
 from repro.parallel import sharding as psharding
 from repro.runtime import trace
 
@@ -117,10 +115,7 @@ class TopologyConfig:
             raise ValueError("push_every must be >= 1")
         if self.passthrough and self.n_leaves != 1:
             raise ValueError("passthrough is the 1-leaf identity topology")
-        if self.root_aggregator not in agg.AGGREGATORS:
-            raise ValueError(f"unknown root aggregator "
-                             f"{self.root_aggregator!r}; "
-                             f"have {sorted(agg.AGGREGATORS)}")
+        agg.check_aggregator(self.root_aggregator, "root aggregator")
 
 
 def parse_topology(spec, **overrides) -> TopologyConfig:
@@ -230,7 +225,6 @@ class Topology:
             self.transport = None
             self._server_acks = None
             self._flat = None
-            self._use_vec = False
         else:
             # the per-leaf downlink ack state lives in a topology-owned
             # registry, NOT inside the transport: it must survive the root
@@ -244,13 +238,10 @@ class Topology:
                 frac=config.server_frac, raw_bytes=model_bytes, mesh=mesh,
                 ack_registry=self._server_acks)
             self._bind_tuner(self.transport)
-            # same fast-path/fallback rules as the leaf servers, shared
-            # helpers so the tiers can never drift apart
-            self._flat = flatbuf.flat_state_for(weights, mesh=mesh)
-            if self._flat is not None:
-                self._flat.server_opt = self.server_opt
-            self._use_vec = agg.use_flat_vec(self._flat, self.transport,
-                                             config.root_aggregator)
+            # the same packed merge as the leaf servers, over the bundle
+            # the root transport resolved
+            self._flat = flatbuf.FlatServerState(weights, mesh=mesh)
+            self._flat.server_opt = self.server_opt
         # passthrough: finalize() replaces the root history with the
         # leaf's verbatim, so seeding it with an eval would be dead work
         self.history: List[HistoryPoint] = [] if config.passthrough else [
@@ -402,8 +393,7 @@ class Topology:
             lf.link.restore_uplink(payload)
             return
         self.total_up_bytes += payload.wire_bytes   # bytes crossed the wire
-        contrib = (lf.link.decode_up_vec(payload) if self._use_vec
-                   else lf.link.decode_up_tree(payload))
+        contrib = lf.link.decode_up_vec(payload)
         prev = self._pending.get(lf.lid)
         if prev is not None:
             # a second push landed before the barrier merged the first
@@ -455,20 +445,8 @@ class Topology:
         if self.cfg.push == "async":
             stale = max(u.staleness for u in ups)
             alpha = self._alpha * (1.0 + stale) ** (-self.cfg.root_stale_pow)
-        if self._use_vec and ws is not None:
-            self.weights = self._flat.merge_rows(
-                self.weights, [u.weights for u in ups], ws, alpha)
-        elif self._flat is not None and ws is not None:
-            self.weights = self._flat.merge(
-                self.weights, [u.weights for u in ups], ws, alpha)
-        else:
-            merged = agg.AGGREGATORS[self.cfg.root_aggregator](ups)
-            mixed = agg.mix_into(self.weights, merged, alpha)
-            if self.server_opt is not None:
-                # tree fallback: per-leaf reference optimizer path (the
-                # flat substrate runs the fused pass in _finish instead)
-                mixed = self.server_opt.step_tree(self.weights, mixed)
-            self.weights = mixed
+        self.weights = self._flat.merge_rows(
+            self.weights, [u.weights for u in ups], ws, alpha)
         self.version += 1
         acc = self._accuracy()
         alive = sum(1 for lf in self.leaves.values() if not lf.dead)
@@ -553,11 +531,7 @@ class Topology:
             # nothing merged past the snapshot (every sync leaf; an idle
             # async one), the identity check keeps the install an exact
             # replace.
-            if server._flat is not None:
-                tree = server._flat.apply_delta(tree, server.weights, base)
-            else:
-                tree = jax.tree.map(lambda g, cur, b: g + (cur - b),
-                                    tree, server.weights, base)
+            tree = server._flat.apply_delta(tree, server.weights, base)
         server.install_global(tree)
         lf.base_root_version = v_enc
         if not lf.started:
@@ -673,9 +647,9 @@ class Topology:
             tr.tuner.__dict__.update(
                 {k: v for k, v in old.tuner.__dict__.items()
                  if k not in ("_bw_of", "_rep_bw")})
+        # same template structure and mesh: bundle_for hands the rebuilt
+        # transport the bundle the root's row buffer already uses
         self.transport = tr
-        self._use_vec = agg.use_flat_vec(self._flat, tr,
-                                         self.cfg.root_aggregator)
         for lf in self.leaves.values():
             if lf.dead:
                 continue

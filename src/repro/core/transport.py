@@ -930,13 +930,6 @@ class Link:
             return self.t.bundle.pack(payload.data)
         return self._codec_apply(payload.data, spec, self.tx_base)
 
-    def decode_up_tree(self, payload: Payload):
-        """Payload -> pytree (the per-leaf reference path, kept for
-        ``REPRO_AGG_PATH=tree`` parity and non-packable weight trees)."""
-        if not CODECS[payload.codec].delta:
-            return payload.data
-        return self.t.bundle.unpack(self.decode_up_vec(payload))
-
     def restore_uplink(self, payload: Payload) -> None:
         """Credit a never-applied uplink's mass back into the EF residual:
         encode debits the residual assuming delivery, so a transfer that is
@@ -972,10 +965,10 @@ class Transport:
 
     ``codec`` names the uplink codec; ``down_codec`` the downlink one
     (``None`` = symmetric, i.e. the same codec both ways; pass ``"raw"``
-    for the PR-2-era uplink-only compression).  ``raw_bytes`` defaults to
-    the template's native byte size; pass the server's ``model_bytes`` to
-    pin it (required for non-packable weight trees, where only the ``raw``
-    codec applies).  ``mesh`` (the server's 1-D ``agg`` mesh) resolves the
+    for the PR-2-era uplink-only compression).  ``raw_bytes`` (the wire size
+    of a raw transfer) defaults to the template's native byte size; pass
+    the server's ``model_bytes`` to pin it.  The template must pack
+    (``flatbuf.bundle_for`` rejects it otherwise).  ``mesh`` (the server's 1-D ``agg`` mesh) resolves the
     mesh-aware bundle, so links hold and codec shard-local slices;
     ``ack_registry`` shares per-worker downlink ack state across servers
     (multi-aggregator topologies).
@@ -1010,19 +1003,9 @@ class Transport:
         self.interpret = interpret
         self.mesh = mesh
         self._ack_registry = ack_registry
-        self.bundle = (flatbuf.bundle_for(template, mesh)
-                       if flatbuf.packable(template) else None)
-        if self.bundle is None and (self.spec_up.delta or
-                                    self.spec_down.delta):
-            raise ValueError(
-                f"codec {codec!r}/{down_codec!r} needs a packable weight "
-                "tree; only 'raw' works with non-array leaves")
-        if raw_bytes is not None:
-            self.raw_bytes = int(raw_bytes)
-        elif self.bundle is not None:
-            self.raw_bytes = self.bundle.raw_bytes
-        else:
-            raise ValueError("non-packable template needs raw_bytes")
+        self.bundle = flatbuf.bundle_for(template, mesh)
+        self.raw_bytes = int(raw_bytes if raw_bytes is not None
+                             else self.bundle.raw_bytes)
         # auto mode: the per-link codec/frac resolver (core/autotune.py);
         # bandwidth sources are bound by whoever owns the estimator
         # (experiment.run_fl / topology.build_topology)
@@ -1066,10 +1049,6 @@ class Transport:
     @property
     def down_codec(self) -> str:
         return self.spec_down.name
-
-    @property
-    def flat_capable(self) -> bool:
-        return self.bundle is not None
 
     @property
     def tracks_tx_base(self) -> bool:
@@ -1155,8 +1134,6 @@ class Transport:
         schedule — raw while no rate is known or a forced warmup lasts,
         the compressed pick afterwards — so selection's BytesSpec
         callables become time-varying per round."""
-        if self.bundle is None:
-            return int(self.raw_bytes * self._retx_factor())
         spec, frac = self.spec_down, self.frac
         if self.auto_down:
             name, frac = self.tuner.steady_choice(self._retx_factor())
@@ -1169,8 +1146,6 @@ class Transport:
         """Per-response uplink estimate from the codec spec (top-k codecs:
         assumes exactly k survivors); auto mode prices the tuner's current
         steady choice, see :meth:`expected_down_bytes`."""
-        if self.bundle is None:
-            return int(self.raw_bytes * self._retx_factor())
         spec, frac = self.spec_up, self.frac
         if self.auto_up:
             name, frac = self.tuner.steady_choice(self._retx_factor())

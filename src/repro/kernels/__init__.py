@@ -2,15 +2,10 @@
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
 
-import os as _os
-
-
 def use_pallas_default() -> bool:
-    """Shared Pallas-dispatch policy: REPRO_FLAT_PALLAS overrides, else
-    Pallas only on TPU (interpret mode would serialise per block on CPU)."""
+    """Shared Pallas-dispatch policy: the platform decides — Pallas only
+    on TPU (interpret mode would serialise per block on CPU)."""
     import jax
-    if _os.environ.get("REPRO_FLAT_PALLAS"):
-        return _os.environ["REPRO_FLAT_PALLAS"] != "0"
     return jax.default_backend() == "tpu"
 
 

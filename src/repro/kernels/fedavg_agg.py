@@ -89,7 +89,7 @@ def fedavg_mix_flat(stacked: jnp.ndarray, weights: jnp.ndarray,
     Returns ``server_scale * server + weights @ stacked``:
 
       * ``server_scale = 1 - alpha`` with ``weights = alpha * w_hat`` is the
-        FedAsync ``mix_into`` damping fused with the weighted sum;
+        FedAsync server-mixing damping fused with the weighted sum;
       * ``server_scale = 1`` with signed weights is the delta-accumulate form
         (``server + sum_i w_i * delta_i``) used by ``async_delta`` mode.
 

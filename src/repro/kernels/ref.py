@@ -5,6 +5,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # the merge oracles contract at full f32 precision, as the kernels do
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -38,6 +39,33 @@ def reference_fedavg(stacked, weights):
     return jnp.einsum("wn,w->n", stacked.astype(jnp.float32),
                       weights.astype(jnp.float32),
                       precision=HIGHEST).astype(stacked.dtype)
+
+
+def reference_weighted_mean(trees, weights):
+    """Per-leaf oracle for the packed merge's weighted sum: W reads + W-1
+    adds per leaf, weights normalised as ``flatbuf.normalized_weights``."""
+    w = np.asarray(weights, dtype=np.float64)
+    w = (w / w.sum()).astype(np.float32)
+
+    def agg(*leaves):
+        out = jnp.zeros_like(leaves[0], dtype=jnp.float32)
+        for wi, leaf in zip(w, leaves):
+            out = out + wi * leaf.astype(jnp.float32)
+        return out.astype(leaves[0].dtype)
+    return jax.tree.map(agg, *trees)
+
+
+def reference_mix_into(server_weights, aggregate, alpha: float = 1.0):
+    """Per-leaf oracle for server-side mixing:
+    ``M_{i+1} = (1-alpha)*M_i + alpha*aggregate``.  alpha=1 reproduces the
+    thesis' replace-on-aggregate; alpha<1 is the async-FL damping for
+    stale single-worker merges."""
+    if alpha >= 1.0:
+        return aggregate
+    return jax.tree.map(
+        lambda s, a: ((1 - alpha) * s.astype(jnp.float32)
+                      + alpha * a.astype(jnp.float32)).astype(s.dtype),
+        server_weights, aggregate)
 
 
 def reference_fedavg_sharded(stacked, weights, server, server_scale,
@@ -124,6 +152,27 @@ def reference_dequant_add(q, scale, base):
     """Oracle for the fused dequantise + delta-apply decode:
     ``base + q * scale``. q: (N,) int8; base: (N,) f32; scale: scalar."""
     return base.astype(jnp.float32) + q.astype(jnp.float32) * scale
+
+
+def topk_compress(x: jnp.ndarray, frac: float):
+    """Per-tensor oracle for top-k sparsification: keep the largest-|.|
+    ``frac`` of entries. Returns (values, mask)."""
+    flat = x.reshape(-1)
+    k = max(1, int(flat.size * frac))
+    thresh = jax.lax.top_k(jnp.abs(flat), k)[0][-1]
+    mask = (jnp.abs(x) >= thresh).astype(x.dtype)
+    return x * mask, mask
+
+
+def int8_quantize(x: jnp.ndarray):
+    """Per-tensor oracle for int8 linear quantisation: (q, scale)."""
+    scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-12) / 127.0
+    q = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
+    return q, scale
+
+
+def int8_dequantize(q: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
+    return q.astype(jnp.float32) * scale
 
 
 def reference_wkv(r, k, v, w, u):

@@ -6,8 +6,8 @@ merge paths on 1/2/4-device CPU meshes:
   * **sharded** — ``FlatServerState(mesh=agg_mesh(d))``: N-sharded rows +
     server mirror, per-shard fused merge;
   * **fused**   — the single-device flat fast path (PR 1);
-  * **tree**    — the per-leaf reference (``REPRO_AGG_PATH=tree``
-    semantics: ``aggregation._weighted_mean`` + ``mix_into``).
+  * **tree**    — the per-leaf oracles ``ref.reference_weighted_mean`` +
+    ``ref.reference_mix_into``.
 
 Reduction-order LSB tolerance (the ROADMAP "Known LSB caveat",
 documented here because this tier enforces it): the flat paths reduce
@@ -37,14 +37,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import TABLE_4_1, aggregation as agg, flatbuf, make_setup, \
-    run_fl
+from repro.core import TABLE_4_1, flatbuf, make_setup, run_fl
 from repro.kernels import fedavg_agg, ref
 from repro.parallel import sharding as psh
 
 MESH_SIZES = [1, 2, 4]
 TOL_TREE = 5e-6          # flat-vs-tree reduction-order drift per merge
-TOL_ACC = 1e-5           # compounded over a short system run
 
 SETUP_KW = dict(seed=0, noise=0.25, batch_size=32, het="strong")
 
@@ -184,7 +182,8 @@ def test_sharded_merge_matches_tree_reference(d):
     ws = [1.0, 0.5, 2.0, 0.25]
     for alpha in (1.0, 0.6):
         out = st.merge(server, ups, ws, alpha=alpha)
-        expect = agg.mix_into(server, agg._weighted_mean(ups, ws), alpha)
+        expect = ref.reference_mix_into(
+            server, ref.reference_weighted_mean(ups, ws), alpha)
         assert _max_err(out, expect) < TOL_TREE
 
 
@@ -299,25 +298,6 @@ def test_run_fl_sharded_empty_round_noop(d):
     assert _rec(h1) == _rec(h0)
 
 
-def test_run_fl_sharded_vs_forced_tree_path(monkeypatch):
-    """REPRO_AGG_PATH=tree (per-leaf reference end to end) vs the sharded
-    substrate: same schedule and bytes, accuracy within the documented
-    tolerance (raw transport keeps byte sizes static — see the ROADMAP
-    caveat for why compressed-codec kept-counts may drift)."""
-    monkeypatch.setenv("REPRO_AGG_PATH", "tree")
-    ht = run_fl(make_setup(TABLE_4_1["mnist_even"], **SETUP_KW),
-                mode="sync", selector="all", epochs_per_round=2,
-                max_rounds=3)
-    monkeypatch.delenv("REPRO_AGG_PATH")
-    hs = run_fl(make_setup(TABLE_4_1["mnist_even"], **SETUP_KW),
-                mode="sync", selector="all", epochs_per_round=2,
-                max_rounds=3, server_mesh=1)
-    assert [(p.version, p.n_updates, p.up_bytes, p.down_bytes) for p in ht] \
-        == [(p.version, p.n_updates, p.up_bytes, p.down_bytes) for p in hs]
-    for a, b in zip(ht, hs):
-        assert abs(a.accuracy - b.accuracy) < TOL_ACC
-
-
 # ---------------- multi-device coverage inside the default tier ----------
 
 def test_multidevice_parity_subprocess():
@@ -354,8 +334,9 @@ def _subprocess_parity_main():
             a = st.merge(server, ups, ws, alpha=alpha)
             b = fused.merge(server, ups, ws, alpha=alpha)
             assert _bit_equal(a, b), f"d={d} alpha={alpha}"
-            assert _max_err(a, agg.mix_into(
-                server, agg._weighted_mean(ups, ws), alpha)) < TOL_TREE
+            assert _max_err(a, ref.reference_mix_into(
+                server, ref.reference_weighted_mean(ups, ws), alpha)) \
+                < TOL_TREE
         per_dev = st.row_bytes_by_device()
         assert len(per_dev) == d
         assert set(per_dev.values()) == {4 * st.bundle.padded_size * 4 // d}

@@ -268,11 +268,10 @@ def test_root_failover_carries_server_opt_state():
     opt = topo.server_opt
     assert opt is not None and opt.momentum == 0.9
     # pre-death merges built momentum; post-death merges kept using it
-    assert opt._m is not None or opt._m_tree is not None
+    assert opt._m is not None
     # the same object is wired into the flat substrate's merge tail (the
     # substrate survives _promote_root — state rides structurally)
-    if topo._flat is not None:
-        assert topo._flat.server_opt is opt
+    assert topo._flat.server_opt is opt
     # rebase dropped the stale anchor; the post-failover step re-anchored
     # on the promoted model (prev tracking alive again)
     assert opt._prev_tree is topo.weights

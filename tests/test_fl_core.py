@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import aggregation as agg
+from repro.core import aggregation as agg, flatbuf
 from repro.core.estimator import TimeEstimator, WorkerProfile
 from repro.core.selection import (RMinRMaxSelector, TimeBasedSelector,
                                   AllSelector)
@@ -22,17 +22,25 @@ def _tree(rng, scale=1.0):
 
 # ---------------- aggregation ----------------
 
+def _merge(name, ups):
+    """Replace-on-aggregate (alpha 1) of ``ups`` under aggregator ``name``
+    through the packed merge the servers run."""
+    trees = [u.weights for u in ups]
+    return flatbuf.FlatServerState(trees[0]).merge(
+        trees[0], trees, agg.update_weights(name, ups), alpha=1.0)
+
+
 def test_fedavg_identity():
     t = _tree(0)
-    out = agg.fedavg([agg.WorkerUpdate(weights=t) for _ in range(4)])
+    out = _merge("fedavg", [agg.WorkerUpdate(weights=t) for _ in range(4)])
     assert all(jnp.allclose(a, b, atol=1e-6)
                for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(t)))
 
 
 def test_fedavg_mean_of_two():
     t1, t2 = _tree(1), _tree(2)
-    out = agg.fedavg([agg.WorkerUpdate(weights=t1),
-                      agg.WorkerUpdate(weights=t2)])
+    out = _merge("fedavg", [agg.WorkerUpdate(weights=t1),
+                            agg.WorkerUpdate(weights=t2)])
     expect = jax.tree.map(lambda a, b: (a + b) / 2, t1, t2)
     assert all(jnp.allclose(a, b, atol=1e-6)
                for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(expect)))
@@ -41,8 +49,8 @@ def test_fedavg_mean_of_two():
 def test_weighted_equals_fedavg_when_uniform():
     trees = [_tree(i) for i in range(3)]
     ups = [agg.WorkerUpdate(weights=t, staleness=0, n_data=1) for t in trees]
-    a = agg.fedavg(ups)
-    b = agg.weighted_fedavg(ups)
+    a = _merge("fedavg", ups)
+    b = _merge("linear", ups)
     assert all(jnp.allclose(x, y, atol=1e-6)
                for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
 
@@ -51,7 +59,7 @@ def test_kernel_fedavg_matches_tree_fedavg():
     from repro.kernels.ops import fedavg_aggregate
     trees = [_tree(i) for i in range(3)]
     ups = [agg.WorkerUpdate(weights=t) for t in trees]
-    a = agg.fedavg(ups)
+    a = _merge("fedavg", ups)
     b = fedavg_aggregate(trees, jnp.ones((3,)), interpret=True)
     assert all(jnp.allclose(x, y, atol=1e-5)
                for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))

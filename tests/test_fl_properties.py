@@ -10,10 +10,10 @@ import pytest
 hypothesis = pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st   # noqa: E402
 
-from repro.core import aggregation as agg                  # noqa: E402
-from repro.core.compression import topk_compress           # noqa: E402
+from repro.core import aggregation as agg, flatbuf         # noqa: E402
 from repro.core.selection import RandomSelector            # noqa: E402
 from repro.core.estimator import WorkerProfile             # noqa: E402
+from repro.kernels.ref import topk_compress                # noqa: E402
 
 
 def _tree(rng, scale=1.0):
@@ -37,7 +37,8 @@ def test_weighted_fedavg_convexity(stalenesses):
     trees = [_tree(i) for i in range(len(stalenesses))]
     ups = [agg.WorkerUpdate(weights=t, staleness=s, n_data=1)
            for t, s in zip(trees, stalenesses)]
-    out = agg.weighted_fedavg(ups)
+    out = flatbuf.FlatServerState(trees[0]).merge(
+        trees[0], trees, agg.update_weights("linear", ups), alpha=1.0)
     for leaf_out, *leaf_ins in zip(jax.tree.leaves(out),
                                    *[jax.tree.leaves(t) for t in trees]):
         lo = jnp.min(jnp.stack(leaf_ins), axis=0)

@@ -1,7 +1,9 @@
-"""Flat-buffer aggregation fast path: pack/unpack round trips, numeric
-parity of the fused flat merge against the per-leaf `_weighted_mean`
-reference and `mix_into`, the fused Pallas kernel (interpret mode), the
-delta-accumulate variant, and the rewired server/fl_round call sites."""
+"""Flat-buffer aggregation, the federation's one merge path: pack/unpack
+round trips, numeric parity of the fused flat merge against the per-leaf
+oracles `ref.reference_weighted_mean` and `ref.reference_mix_into`, the
+fused Pallas kernel (interpret mode), the delta-accumulate variant, the
+rewired server/fl_round call sites, and the merge owners' construction-
+time rejection of weights that do not pack and of unknown aggregators."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -69,8 +71,10 @@ def test_bundle_cache_hit():
 def test_flat_weighted_mean_matches_reference(W):
     trees = [_ragged_tree(i) for i in range(W)]
     ws = [0.5 + i for i in range(W)]
-    flat = agg._weighted_mean_flat(trees, ws)
-    tree_ref = agg._weighted_mean(trees, ws)
+    b = flatbuf.bundle_for(trees[0])
+    flat = b.unpack(flatbuf.fused_weighted_sum(
+        b.pack_many(trees), flatbuf.normalized_weights(ws)))
+    tree_ref = ref.reference_weighted_mean(trees, ws)
     assert _max_err(flat, tree_ref) < 1e-5
 
 
@@ -81,7 +85,8 @@ def test_server_state_merge_matches_mix_into(alpha):
     ws = [1.0, 0.25, 2.0]
     st = flatbuf.FlatServerState(server)
     out = st.merge(server, trees, ws, alpha=alpha)
-    expect = agg.mix_into(server, agg._weighted_mean(trees, ws), alpha)
+    expect = ref.reference_mix_into(
+        server, ref.reference_weighted_mean(trees, ws), alpha)
     assert _max_err(out, expect) < 1e-5
 
 
@@ -94,7 +99,8 @@ def test_server_state_merge_repeated_rounds_reuse_mirror():
         trees = [_ragged_tree(100 + 10 * r + i) for i in range(2 + r % 2)]
         ws = [1.0] * len(trees)
         server = st.merge(server, trees, ws, alpha=0.5)
-        expect = agg.mix_into(expect, agg._weighted_mean(trees, ws), 0.5)
+        expect = ref.reference_mix_into(
+            expect, ref.reference_weighted_mean(trees, ws), 0.5)
     assert _max_err(server, expect) < 1e-5
 
 
@@ -102,8 +108,9 @@ def test_merge_rejects_zero_weights():
     t = _ragged_tree(0)
     with pytest.raises(ValueError):
         flatbuf.FlatServerState(t).merge(t, [t], [0.0])
+    vec = flatbuf.bundle_for(t).pack(t)
     with pytest.raises(ValueError):
-        agg.weighted_mean([t, t], [0.0, 0.0])
+        flatbuf.FlatServerState(t).merge_rows(t, [vec, vec], [0.0, 0.0])
 
 
 def test_stale_rows_cannot_poison_later_merges():
@@ -114,7 +121,7 @@ def test_stale_rows_cannot_poison_later_merges():
     bad = {"a": jnp.full((300,), jnp.inf)}
     merged = st.merge(t, [t, bad], [1.0, 1.0])           # rows poisoned
     out = st.merge(merged, [{"a": jnp.full((300,), 2.0)}], [1.0], alpha=0.5)
-    # reference: mix_into(merged=inf...) would also be inf at alpha<1 with a
+    # reference: the mix oracle (merged=inf...) would also be inf at alpha<1 with a
     # non-finite server — so check the stale ROW specifically, alpha>=1:
     out = st.merge(out, [{"a": jnp.full((300,), 3.0)}], [1.0], alpha=1.0)
     assert bool(jnp.all(jnp.isfinite(out["a"])))
@@ -122,7 +129,7 @@ def test_stale_rows_cannot_poison_later_merges():
 
 
 def test_alpha_one_ignores_nonfinite_server():
-    """alpha>=1 is replace-on-aggregate: like mix_into's short-circuit, the
+    """alpha>=1 is replace-on-aggregate: like the mix oracle's short-circuit, the
     server buffer must not be read (0 * inf = NaN otherwise)."""
     t = {"a": jnp.ones((300,))}
     st = flatbuf.FlatServerState(t)
@@ -178,16 +185,17 @@ def test_flat_pallas_path_matches_xla_path():
 # ---------------- rewired call sites ----------------
 
 def test_aggregators_wrapper_still_pytree_api():
+    """Every registered aggregator's scalar weights, merged by the packed
+    path at alpha 1, == the per-leaf oracle with the same weights."""
     trees = [_ragged_tree(i) for i in range(3)]
     ups = [agg.WorkerUpdate(weights=t, staleness=i, n_data=1 + i)
            for i, t in enumerate(trees)]
-    for name in agg.AGGREGATORS:
-        out = agg.AGGREGATORS[name](ups)
-        assert jax.tree.structure(out) == jax.tree.structure(trees[0])
-        # flat wrapper == per-leaf reference with the same scalar weights
+    for name in agg.UPDATE_WEIGHT_FNS:
         ws = agg.update_weights(name, ups)
-        assert _max_err(out, agg._weighted_mean(
-            [u.weights for u in ups], ws)) < 1e-5
+        out = flatbuf.FlatServerState(trees[0]).merge(
+            trees[0], trees, ws, alpha=1.0)
+        assert jax.tree.structure(out) == jax.tree.structure(trees[0])
+        assert _max_err(out, ref.reference_weighted_mean(trees, ws)) < 1e-5
 
 
 def test_fl_round_flat_matches_per_leaf_einsum():
@@ -265,8 +273,7 @@ def test_delta_vec_matches_apply_delta():
 
 def test_server_aggregate_routes_through_flat(monkeypatch):
     """The server's merge lands decoded flat rows via
-    FlatServerState.merge_rows (fast path), not the pytree AGGREGATORS
-    wrapper."""
+    FlatServerState.merge_rows, once per round."""
     from repro.core import TABLE_4_1, make_setup, run_fl
 
     calls = {"merge": 0}
@@ -283,3 +290,39 @@ def test_server_aggregate_routes_through_flat(monkeypatch):
                max_rounds=3)
     assert calls["merge"] == 3
     assert len(h) == 4
+
+
+# ---------------- construction-time rejection ----------------
+
+def _server(weights, aggregator="fedavg"):
+    from repro.core.estimator import TimeEstimator
+    from repro.core.events import EventLoop
+    from repro.core.selection import make_selector
+    from repro.core.server import AggregationServer
+    est = TimeEstimator()
+    return AggregationServer(
+        weights=weights, loop=EventLoop(), estimator=est,
+        selector=make_selector("all", est, 1000), eval_fn=lambda w: 0.0,
+        model_bytes=1000, aggregator=aggregator)
+
+
+def _topology(weights):
+    from repro.core.events import EventLoop
+    from repro.core.topology import Topology, TopologyConfig
+    return Topology(weights=weights, loop=EventLoop(), eval_fn=lambda w: 0.0,
+                    model_bytes=1000, config=TopologyConfig(n_leaves=2))
+
+
+@pytest.mark.parametrize("build", [_server, _topology],
+                         ids=["server", "topology"])
+def test_merge_owners_reject_nonpackable_weights(build):
+    """A weight tree with a non-array leaf has no merge path: the merge
+    owner refuses it at construction, naming the leaf."""
+    weights = {"w": jnp.ones((4,)), "tag": "not-an-array"}
+    with pytest.raises(ValueError, match="'tag'"):
+        build(weights)
+
+
+def test_server_rejects_unknown_aggregator():
+    with pytest.raises(ValueError, match="fedavg"):
+        _server({"w": jnp.ones((4,))}, aggregator="median")

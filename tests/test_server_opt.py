@@ -7,7 +7,7 @@ Three layers, mirroring tests/test_agg_sharded.py:
     shard_map'ed variant against the sliced oracle, which must agree
     EXACTLY (the step is elementwise — no cross-shard reduction at all).
   * **substrate** — the fused ``step_vec`` pass inside the FlatServerState
-    merge tail against the per-leaf ``step_tree`` reference, within the
+    merge tail against ``ref.reference_server_opt`` mapped per leaf, within the
     ROADMAP "Known LSB caveat" tolerance (the merge feeding the optimizer
     reduces in a different order on the two paths; the optimizer itself
     adds nothing — it is elementwise).
@@ -111,22 +111,25 @@ def test_opt_kernel_sharded_matches_sliced_oracle(adam, d):
                                    rtol=1e-6, atol=1e-6)
 
 
-# ---------------- fused step_vec vs per-leaf step_tree ----------------
+# ---------------- fused step_vec vs the per-leaf oracle ----------------
 
 @pytest.mark.parametrize("name,kw", OPTS)
 def test_step_vec_matches_step_tree(name, kw):
     """Drive the same merge sequence through a FlatServerState with the
-    optimizer attached (fused packed pass) and through mix + step_tree
-    (the REPRO_AGG_PATH=tree reference); the installs must agree within
-    the merge's reduction-order tolerance."""
+    optimizer attached (fused packed pass) and through a per-leaf weighted
+    mean + ``ref.reference_server_opt`` mapped over the leaves (state
+    carried here); the installs must agree within the merge's
+    reduction-order tolerance."""
     template = {"w": jax.random.normal(jax.random.PRNGKey(0), (37, 41)),
                 "b": jax.random.normal(jax.random.PRNGKey(1), (53,))}
     opt_flat = so.make_server_opt(name, **kw)
-    opt_tree = so.make_server_opt(name, **kw)
     flat = flatbuf.FlatServerState(template)
     flat.server_opt = opt_flat
     server_f = template
     server_t = template
+    sc = opt_flat._scalars()
+    zeros = jax.tree.map(lambda l: jnp.zeros(l.shape, jnp.float32), template)
+    m_t, v_t = zeros, zeros
     rng = np.random.RandomState(0)
     for step in range(4):
         ups = [jax.tree.map(
@@ -135,13 +138,22 @@ def test_step_vec_matches_step_tree(name, kw):
                    server_t) for s in range(3)]
         w = [1.0, 2.0, 1.0]
         server_f = flat.merge(server_f, ups, w, alpha=1.0)
-        # tree reference: plain weighted mean (alpha=1 install) + step_tree
+        # per-leaf reference: plain weighted mean (alpha=1 install), then
+        # the optimizer oracle on every leaf
         tot = sum(w)
         mixed = jax.tree.map(
             lambda *ls: sum(wi / tot * l.astype(jnp.float32)
                             for wi, l in zip(w, ls)).astype(ls[0].dtype),
             *ups)
-        server_t = opt_tree.step_tree(server_t, mixed)
+        outs = jax.tree.map(
+            lambda p, mg, m, v: ref.reference_server_opt(
+                p, mg, m, v, sc, adam=opt_flat.adam),
+            server_t, mixed, m_t, v_t)
+        is_out = lambda x: isinstance(x, tuple)
+        server_t = jax.tree.map(lambda o: o[0], outs, is_leaf=is_out)
+        m_t = jax.tree.map(lambda o: o[1], outs, is_leaf=is_out)
+        if opt_flat.adam:
+            v_t = jax.tree.map(lambda o: o[2], outs, is_leaf=is_out)
         err = max(float(jnp.max(jnp.abs(a.astype(jnp.float32)
                                         - b.astype(jnp.float32))))
                   for a, b in zip(jax.tree.leaves(server_f),

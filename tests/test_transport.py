@@ -12,7 +12,6 @@ import pytest
 
 from repro.core import TABLE_4_1, make_setup, run_fl, time_to_accuracy
 from repro.core import flatbuf, transport
-from repro.core.compression import ErrorFeedbackCompressor
 from repro.core.estimator import TimeEstimator, WorkerProfile
 from repro.core.warehouse import DataWarehouse
 from repro.kernels import ref, topk_quant
@@ -159,12 +158,13 @@ def test_unknown_codec_rejected():
         transport.Transport(_model(0), codec="gzip")
 
 
-def test_nonpackable_only_raw():
-    with pytest.raises(ValueError):
-        transport.Transport({"a": "not-an-array"}, codec="int8")
-    t = transport.Transport({"a": "not-an-array"}, codec="raw",
+@pytest.mark.parametrize("codec", ["raw", "int8"])
+def test_nonpackable_only_raw(codec):
+    """A weight tree with a non-array leaf has no path through the
+    transport under any codec: rejected at construction, naming the leaf."""
+    with pytest.raises(ValueError, match="'a'"):
+        transport.Transport({"a": "not-an-array"}, codec=codec,
                             raw_bytes=123)
-    assert t.raw_bytes == 123 and not t.flat_capable
 
 
 # ---------------- downlink codecs: last-acked base protocol ----------------
@@ -457,19 +457,23 @@ def test_estimator_downlink_estimate_scales_with_codec_bytes():
 
 # ---------------- error feedback across rounds ----------------
 
-def test_link_error_feedback_recovers_mass():
+@pytest.mark.parametrize("model,frac,steps,scale", [
+    (_model(0), 0.2, 12, 0.01),
+    ({"g": jnp.zeros((32, 16))}, 0.25, 30, 0.1),
+], ids=["two_leaf", "one_leaf"])
+def test_link_error_feedback_recovers_mass(model, frac, steps, scale):
     """Cumulative reconstructed deltas + residual == cumulative true deltas
     (the EF contraction property, now per-link)."""
-    base = _model(0)
-    t = transport.Transport(base, codec="topk_ef", frac=0.2)
+    base = model
+    t = transport.Transport(base, codec="topk_ef", frac=frac)
     link = t.link("w0")
     total_in = jnp.zeros((t.bundle.padded_size,))
     total_out = jnp.zeros((t.bundle.padded_size,))
     cur = base
-    for i in range(12):
+    for i in range(steps):
         link.encode_down(cur)
         new = jax.tree.map(
-            lambda l, k=i: l + 0.01 * jax.random.normal(
+            lambda l, k=i: l + scale * jax.random.normal(
                 jax.random.PRNGKey(100 + k), l.shape), cur)
         up = link.encode_up(new)
         total_in += t.bundle.pack(new) - link.tx_base
@@ -479,32 +483,28 @@ def test_link_error_feedback_recovers_mass():
 
 
 def test_compressor_parity_with_flat_codec_single_leaf():
-    """The refactored pytree ErrorFeedbackCompressor == the flat codec on a
-    single-leaf tree (global top-k == per-leaf top-k there), including the
-    wire-byte count, for both the flat path and REPRO_AGG_PATH=tree."""
+    """A topk_ef / topk_ef+int8 link uplink on a one-leaf tree ==
+    ``ef_topk_encode`` on the packed vector with the residual carried by
+    hand: same reconstruction, same residual, same wire bytes."""
+    base = {"g": jnp.zeros((1000,))}
     deltas = [{"g": jax.random.normal(jax.random.PRNGKey(i), (1000,))}
               for i in range(4)]
     for quantize in (False, True):
-        flat_c = ErrorFeedbackCompressor(frac=0.1, quantize=quantize)
-        res_vec = jnp.zeros((1024,))
+        t = transport.Transport(
+            base, codec="topk_ef+int8" if quantize else "topk_ef",
+            down_codec="raw", frac=0.1)
+        link = t.link("w0")
+        res_vec = jnp.zeros((t.bundle.padded_size,))
         for d in deltas:
-            bundle = flatbuf.bundle_for(d)
-            x = bundle.pack(d) + res_vec
+            link.encode_down(base)               # the uplink base: zeros
+            up = link.encode_up(d)
             _, recon, res_vec, wire = transport.ef_topk_encode(
-                x, n_params=1000, frac=0.1, quantize=quantize)
-            out, wire_c = flat_c.compress(d)
-            assert wire_c == wire
-            assert _vec_err(bundle.pack(out), recon) < 1e-6
-        assert _vec_err(bundle.pack(flat_c.residual), res_vec) < 1e-6
-
-
-def test_compressor_tree_path_still_works(monkeypatch):
-    monkeypatch.setenv("REPRO_AGG_PATH", "tree")
-    comp = ErrorFeedbackCompressor(frac=0.25, quantize=False)
-    d = {"g": jax.random.normal(jax.random.PRNGKey(0), (64, 8))}
-    recon, wire = comp.compress(d)
-    assert wire < 64 * 8 * 4
-    assert jax.tree.structure(comp.residual) == jax.tree.structure(d)
+                t.bundle.pack(d) + res_vec, n_params=1000, frac=0.1,
+                quantize=quantize)
+            assert up.wire_bytes == wire
+            assert _vec_err(link.decode_up_vec(up) - link.tx_base,
+                            recon) < 1e-6
+        assert _vec_err(link.residual, res_vec) < 1e-6
 
 
 # ---------------- estimator: measured bandwidth ----------------
