@@ -65,8 +65,10 @@ class ModelConfig:
     # --- numerics / memory ---
     loss_chunk: int = 512            # sequence chunk for vocab loss
     remat: bool = True
-    # --- attention impl: "xla" (chunked jnp), "pallas", "pallas_interpret"
-    attn_impl: str = "xla"
+    # --- attention impl: "auto" (the splash kernel where
+    # ``attention.attn_kernel_takes`` says so, else chunked jnp), "xla"
+    # (chunked jnp), "pallas", "pallas_interpret"
+    attn_impl: str = "auto"
     # gradient-accumulation microbatches for the production train shapes
     # (small models need fewer: FSDP weight gathers repeat per microbatch)
     microbatches: int = 4
@@ -141,6 +143,17 @@ class ModelConfig:
         dense = self.n_params() - self.n_layers * (3 * d * self.d_ff * e_here)
         active = self.top_k * e_here / self.n_experts
         return dense + int(self.n_layers * 3 * d * self.d_ff * active)
+
+    @property
+    def n_attn_layers(self) -> int:
+        """Attention layers one forward pass runs: every layer of an
+        attention stack, the shared block's applications in a mamba2
+        hybrid, none in rwkv6."""
+        if self.block_type == "attn":
+            return self.n_layers
+        if self.block_type == "mamba2":
+            return self.n_shared_attn_applications()
+        return 0
 
     def n_shared_attn_applications(self) -> int:
         if self.shared_attn_every <= 0:

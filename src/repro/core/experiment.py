@@ -16,7 +16,7 @@ import numpy as np
 from repro.configs.paper_cnn import CNNConfig, FAST_MNIST_CNN, MNIST_CNN
 from repro.data.synth import (federated_split, make_classification_dataset,
                               partition_split)
-from repro.models import cnn, transformer
+from repro.models import attention, cnn, transformer
 from repro.parallel import sharding as psharding
 from repro.runtime import trace
 from repro.runtime.trace import to_device
@@ -153,6 +153,12 @@ def lm_train_wrapper(params, x, y, epochs, lr, cfg):
     training (and the server packs this one's output) at once, so
     without the wait their memory would be claimed beside them."""
     trace.count("train_tokens", int(x.size) * int(epochs))
+    seq_len = x.shape[-1]
+    if attention.attn_kernel_takes(
+            cfg.attn_impl, attention.platform_of(jax.tree.leaves(params)[0]),
+            seq_len, seq_len, cfg.hd):
+        trace.count("attn_kernel_tokens",
+                    int(x.size) * int(epochs) * cfg.n_attn_layers)
     out = transformer.lm_sgd_train(params, to_device(x), to_device(y),
                                    epochs=int(epochs), lr=lr, cfg=cfg)
     with trace.span("fl.sync", site="lm_train"):

@@ -1,9 +1,15 @@
 """Attention: GQA with RoPE, sliding windows, gemma2 logit soft-capping.
 
-Three execution paths:
+Four execution paths:
+  * ``splash_attention`` — train/prefill on a TPU: JAX's bundled Pallas
+                        splash kernel (forward, dq and dkv kernels), GQA heads
+                        grouped in the kernel and fully masked blocks skipped,
+                        so no score tile goes through HBM. ``attn_kernel_takes``
+                        is the rule of ``cfg.attn_impl='auto'`` that picks it.
   * ``mha_chunked``   — memory-efficient blockwise attention (online softmax)
-                        in pure jnp; used for train/prefill under XLA. Block
-                        bounds are static per query-block, so causal and
+                        in pure jnp; train/prefill where the kernel's rule
+                        does not hold, and always under ``attn_impl='xla'``.
+                        Block bounds are static per query-block, so causal and
                         sliding-window structure statically skips KV blocks
                         (no masked-out FLOPs outside the diagonal band).
   * ``decode_attention`` — single-token attention over a (ring-buffered) KV
@@ -263,6 +269,68 @@ _mha_chunked.defvjp(_mha_chunked_fwd, _mha_chunked_bwd)
 
 
 # ---------------------------------------------------------------------------
+# Splash attention (jax.experimental.pallas.ops.tpu.splash_attention)
+# ---------------------------------------------------------------------------
+
+# every block of the forward, dq and dkv kernels: mha_chunked's own tiling
+SPLASH_BLOCK = 512
+LANES = 128
+
+
+def platform_of(x) -> str:
+    """The platform a concrete array lives on; the default backend for a
+    traced value (or a host array), which is where it will run."""
+    if isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer):
+        return next(iter(x.devices())).platform
+    return jax.default_backend()
+
+
+def attn_kernel_takes(impl: str, platform: str, seq_len: int, kv_len: int,
+                      head_dim: int, *, cached: bool = False) -> bool:
+    """The rule of ``attn_impl='auto'``: train/prefill attention runs in the
+    splash kernel on a TPU, with no decode cache, as many keys as queries,
+    the sequence a multiple of the kernel's block and the head a multiple
+    of the lanes; anything else runs ``mha_chunked``."""
+    return (impl == "auto" and platform == "tpu" and not cached
+            and seq_len == kv_len and seq_len % SPLASH_BLOCK == 0
+            and head_dim % LANES == 0)
+
+
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(seq_len: int, window: int, heads: int, softcap: float,
+                   block: int, interpret: bool):
+    """One kernel (and its mask info) per shape, mask and tiling: causal,
+    or ``q - k < window`` and causal; built once, outside any trace."""
+    from jax.experimental.pallas.ops.tpu import splash_attention as sa
+    b = block
+    blocks = sa.BlockSizes(block_q=b, block_kv=b, block_kv_compute=b,
+                           block_q_dkv=b, block_kv_dkv=b,
+                           block_kv_dkv_compute=b, block_q_dq=b, block_kv_dq=b)
+    shape = (seq_len, seq_len)
+    mask = (sa.LocalMask(shape, window_size=(window - 1, 0), offset=0)
+            if window else sa.CausalMask(shape))
+    with jax.ensure_compile_time_eval():
+        return sa.make_splash_mha(
+            sa.MultiHeadMask([mask] * heads), block_sizes=blocks,
+            attn_logits_soft_cap=softcap or None, head_shards=1,
+            q_seq_shards=1, interpret=interpret)
+
+
+def splash_attention(q, k, v, *, window=0, softcap_val=0.0,
+                     interpret=False):
+    """Causal (``window`` > 0: sliding-window) attention in the splash
+    kernel. q:(B,S,H,hd) k,v:(B,S,Kv,hd), each sequence head-major under a
+    vmap over B.  The kernel does not scale its scores: q comes already
+    multiplied by 1/sqrt(hd) (``attn_apply`` does it in RoPE's f32
+    product, before q's one rounding to bf16)."""
+    B, S, H, hd = q.shape
+    kernel = _splash_kernel(S, window, H, float(softcap_val), SPLASH_BLOCK,
+                            interpret)
+    heads = lambda t: t.swapaxes(1, 2)           # (B,S,h,hd) <-> (B,h,S,hd)
+    return heads(jax.vmap(kernel)(heads(q), heads(k), heads(v)))
+
+
+# ---------------------------------------------------------------------------
 # KV cache (supports ring buffers for sliding-window archs)
 # ---------------------------------------------------------------------------
 
@@ -337,13 +405,22 @@ def attn_apply(params, x, *, cfg, window: int = 0, rope_theta=None,
         o = jnp.einsum("bshk,hkd->bsd", out.astype(dt), params["wo"].astype(dt))
         return o, new_cache
 
+    impl = impl or cfg.attn_impl
+    platform = platform_of(x)
+    kernel = attn_kernel_takes(impl, platform, S, S, q.shape[-1])
     pos = jnp.broadcast_to(jnp.arange(S), (B, S))
-    q = apply_rope(q, pos, theta, yarn)
+    # the kernel takes q scaled: RoPE's f32 product scales it, so q is
+    # rounded once, as mha_chunked's unscaled q is
+    q = apply_rope(q, pos, theta, yarn,
+                   scale=1.0 / math.sqrt(q.shape[-1]) if kernel else 1.0)
     k = apply_rope(k, pos, theta, yarn)
     from repro.parallel import constrain_qkv
     q, k, v = constrain_qkv(q, k, v)
-    impl = impl or cfg.attn_impl
-    if impl == "xla":
+    if kernel:
+        out = splash_attention(q, k, v, window=window,
+                               softcap_val=cfg.attn_softcap,
+                               interpret=platform != "tpu")
+    elif impl in ("auto", "xla"):
         out = mha_chunked(q, k, v, causal=True, window=window,
                           softcap_val=cfg.attn_softcap)
     elif impl in ("pallas", "pallas_interpret"):

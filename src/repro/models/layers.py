@@ -65,10 +65,11 @@ def yarn_freqs(head_dim: int, theta: float, factor: float, original_max: int,
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
-               yarn=None) -> jnp.ndarray:
+               yarn=None, scale: float = 1.0) -> jnp.ndarray:
     """x: (..., S, H, hd); positions: broadcastable to (..., S).  ``yarn``
     (``ModelConfig.rope_yarn``) scales the frequencies and multiplies cos
-    and sin by its attention factor."""
+    and sin by its attention factor; ``scale`` multiplies them too, so a
+    scaled x is rounded to its dtype once, after the f32 product."""
     hd = x.shape[-1]
     if yarn is None:
         freqs = rope_freqs(hd, theta)                    # (hd/2,)
@@ -76,8 +77,9 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
         freqs = yarn_freqs(hd, theta, *yarn[:4])
     ang = positions[..., :, None, None].astype(jnp.float32) * freqs  # (...,S,1,hd/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
-    if yarn is not None:
-        cos, sin = cos * yarn[4], sin * yarn[4]
+    amp = (yarn[4] if yarn is not None else 1.0) * scale
+    if amp != 1.0:
+        cos, sin = cos * amp, sin * amp
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -13,7 +13,7 @@ import pytest
 from repro.configs import get_config
 from repro.configs.mellum2_12b_a2p5b import CONFIG, SHARE
 from repro.core import experiment
-from repro.models import moe, transformer
+from repro.models import attention, layers, moe, transformer
 from repro.models.layers import apply_rope, rope_freqs, yarn_freqs
 from repro.runtime import trace
 
@@ -185,25 +185,66 @@ def test_period_forward_logits_match_the_reference():
     assert rel(tied, want) > 0.5
 
 
-def test_one_local_sgd_step_matches_the_reference():
-    params = f32_params(RED)
-    x, y = tokens(RED, 4, 32)
-    lr = 0.5
-    new = transformer.lm_sgd_train(params, x, y, epochs=1, lr=lr, cfg=RED)
+def assert_step_matches_the_reference(params, new, x, y, lr, whole=0.05):
     with jax.default_matmul_precision("highest"):
         g = jax.grad(ref_loss)(params, x, y, RED)
     want = jax.tree.map(lambda p, gg: -lr * gg, params, g)
     got = jax.tree.map(jnp.subtract, new, params)
     # the f32 weights' change from bf16 gradients: the norm of each leaf's
-    # change within 10% of the reference's, the whole change within 5%
+    # change within 10% of the reference's, the whole change within
+    # ``whole`` (5%)
     flat = lambda t: np.concatenate([np.ravel(v) for v in jax.tree.leaves(t)])
-    assert rel(flat(got), flat(want)) < 0.05
+    assert rel(flat(got), flat(want)) < whole
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         nb = np.linalg.norm(b)
         if nb > 1e-3 * np.linalg.norm(flat(want)):
             assert abs(np.linalg.norm(a) - nb) / nb < 0.1
     # the master weights stay f32
     assert all(v.dtype == F32 for v in jax.tree.leaves(new))
+
+
+def test_one_local_sgd_step_matches_the_reference():
+    params = f32_params(RED)
+    x, y = tokens(RED, 4, 32)
+    lr = 0.5
+    new = transformer.lm_sgd_train(params, x, y, epochs=1, lr=lr, cfg=RED)
+    assert_step_matches_the_reference(params, new, x, y, lr)
+
+
+@pytest.fixture
+def kernel_forced(monkeypatch):
+    """Every attention layer in the splash kernel (interpret mode), at
+    blocks of 128 so that the tiny config's sequences hold whole blocks;
+    no program traced under it outlives the test.  Yields the windows of
+    the layers traced through the kernel."""
+    windows, kernel = [], attention.splash_attention
+
+    def spy(*a, **k):
+        windows.append(k["window"])
+        return kernel(*a, **k)
+    monkeypatch.setattr(attention, "attn_kernel_takes", lambda *a, **k: True)
+    monkeypatch.setattr(attention, "SPLASH_BLOCK", 128)
+    monkeypatch.setattr(attention, "splash_attention", spy)
+    transformer.lm_sgd_train.clear_cache()
+    yield windows
+    transformer.lm_sgd_train.clear_cache()
+
+
+def test_one_local_sgd_step_through_the_kernel_matches_the_reference(
+        kernel_forced, monkeypatch):
+    """At the kernel's smallest block, 128 tokens, bf16 rounding flips
+    routing choices and puts either attention path 6-13% from the
+    reference; computed in f32, the kernel is the step's one departure
+    from the reference's arithmetic, and it lands within 1e-4."""
+    monkeypatch.setattr(layers, "COMPUTE_DTYPE", F32)
+    monkeypatch.setattr(transformer, "COMPUTE_DTYPE", F32)
+    params = f32_params(RED)
+    x, y = tokens(RED, 4, 128)
+    lr = 0.5
+    new = transformer.lm_sgd_train(params, x, y, epochs=1, lr=lr, cfg=RED)
+    assert_step_matches_the_reference(params, new, x, y, lr, whole=1e-4)
+    # each layer of the period went through the kernel, windowed and full
+    assert set(kernel_forced) == set(RED.period) == {8, 0}
 
 
 def test_decode_matches_forward_through_the_period():
@@ -323,6 +364,21 @@ def test_lm_wrappers_count_tokens_and_score_next_ids():
     h, _, _ = transformer.forward(params, RED, tokens=jnp.asarray(x))
     pred = jnp.argmax(transformer.logits_from_hidden(params, RED, h), -1)
     assert acc == pytest.approx(float(jnp.mean(pred == y)))
+
+
+@pytest.mark.parametrize("forced", [True, False])
+def test_attn_kernel_tokens_count_the_kernel_layers(request, forced):
+    """tokens x epochs x attention layers where the rule takes the kernel;
+    none on the CPU, where it keeps the chunked path."""
+    if forced:
+        request.getfixturevalue("kernel_forced")
+    params = f32_params(RED)
+    x, y = (np.asarray(t) for t in tokens(RED, 2, 128))
+    before = trace.counters().get("attn_kernel_tokens", 0)
+    experiment.lm_train_wrapper(params, x, y, 2, lr=0.1, cfg=RED)
+    got = trace.counters().get("attn_kernel_tokens", 0) - before
+    assert got == (2 * 128 * 2 * RED.n_layers if forced else 0)
+    assert RED.n_layers == 8
 
 
 def test_rows_past_the_held_groups_never_reach_the_result(monkeypatch):
